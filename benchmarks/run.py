@@ -1,5 +1,5 @@
-"""Benchmark aggregator — one section per paper table/figure plus the
-harness-required roofline table.  Prints ``name,value,note`` CSV."""
+"""Benchmark aggregator — one section per paper table/figure.  Prints
+``name,value,note`` CSV."""
 from __future__ import annotations
 
 import sys
@@ -11,6 +11,9 @@ sys.path.insert(0, str(Path(__file__).resolve().parent))
 
 
 def main() -> None:
+    from repro.kernels.backend import enable_compile_cache
+    enable_compile_cache()
+
     import dse_sweep
     import faults_bench
     import fig20_generality
@@ -18,7 +21,6 @@ def main() -> None:
     import fig22_sensitivity
     import kernel_bench
     import obs_bench
-    import roofline_table
     import serving_bench
     import simulator_bench
 
@@ -44,13 +46,6 @@ def main() -> None:
         for name, val, note in fn():
             print(f"{name},{val:.4g},{note}")
         print(f"# ({time.time()-t0:.1f}s)")
-
-    print("# --- roofline (from experiments/dryrun.json) ---")
-    try:
-        for name, val, note in roofline_table.rows():
-            print(f"{name},{val:.4g},{note}")
-    except FileNotFoundError:
-        print("# run `python -m repro.launch.dryrun --all` first")
 
 
 if __name__ == "__main__":

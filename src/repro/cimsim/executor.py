@@ -36,9 +36,10 @@ jitted executable with the same bit-exact semantics:
 How the MVM itself executes — compiled Pallas kernel, Pallas
 interpreter, or the XLA-compiled oracle — is a
 ``kernels.backend`` registry decision (see ``KernelRoute``); a route
-the registry cannot satisfy on the active platform surfaces as
-``LoweringError`` so callers keep their documented interpreter
-fallback.
+the registry cannot satisfy on the active platform raises
+``KernelUnsupportedError`` to the caller.  Nothing falls back to the
+interpreter: that runs only when a caller asks for it
+(``use_executor=False``).
 
 Lowering is cached process-wide, keyed by the *content* of the compile
 (``compiler.compile_key_for_plan``) x the crossbar compute params — a
@@ -91,9 +92,7 @@ _SHIFTED_DCOM = {"Add", "Mul", "MatMul"}
 
 class LoweringError(ValueError):
     """The program cannot be trace-lowered bit-exactly (unsupported op,
-    int32 overflow risk, or the backend registry cannot satisfy the
-    requested kernel route on this platform); callers should fall back
-    to the interpreter."""
+    int32 overflow risk, incomplete crossbar coverage)."""
 
 
 def _resolve_executor_route(route: Optional[backend.KernelRoute],
@@ -101,23 +100,20 @@ def _resolve_executor_route(route: Optional[backend.KernelRoute],
                             use_kernel: Optional[bool],
                             interpret: Optional[bool]
                             ) -> backend.KernelRoute:
-    """The executor's MVM route: registry-resolved, LoweringError on an
-    unsupportable request (so callers keep their interpreter fallback).
+    """The executor's MVM route, resolved by the backend registry; an
+    unsupportable request raises ``KernelUnsupportedError``.
 
     ``use_kernel``/``interpret`` keep the pre-registry boolean calling
     convention alive (executor legacy default was the oracle path).
     """
-    try:
-        if use_kernel is not None or interpret is not None:
-            uk = bool(use_kernel)            # legacy default: False
-            legacy = "xla" if not uk else \
-                ("compiled" if interpret is False else "interpret")
-            return backend.resolve("cim_mvm_tiles", mode=legacy)
-        if route is not None:
-            return route
-        return backend.resolve("cim_mvm_tiles", mode=mode)
-    except backend.KernelUnsupportedError as e:
-        raise LoweringError(str(e)) from None
+    if use_kernel is not None or interpret is not None:
+        uk = bool(use_kernel)            # legacy default: False
+        legacy = "xla" if not uk else \
+            ("compiled" if interpret is False else "interpret")
+        return backend.resolve("cim_mvm_tiles", mode=legacy)
+    if route is not None:
+        return route
+    return backend.resolve("cim_mvm_tiles", mode=mode)
 
 
 @dataclasses.dataclass

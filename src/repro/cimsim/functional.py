@@ -605,9 +605,9 @@ def compile_and_verify(graph: Graph, arch: CIMArch, *, level=None,
 
     The fast path (default) lowers the compiled program once with the
     batched executor and verifies all inputs in a single dispatch; a
-    flow the executor cannot lower bit-exactly (``LoweringError``)
-    falls back to op-by-op interpretation, as does
-    ``use_executor=False``.  Extra keyword arguments (``use_pipeline``,
+    flow the executor cannot lower bit-exactly raises (``LoweringError``,
+    ``KernelUnsupportedError``).  Only ``use_executor=False`` verifies
+    op by op through the interpreter.  Extra keyword arguments (``use_pipeline``,
     ``binding``, ``cache``, ...) reach ``compile_graph``, so any DSE
     design point can be verified.  With ``faults`` set the simulated
     crossbars carry the fault map while the reference stays clean, so
@@ -626,28 +626,25 @@ def compile_and_verify(graph: Graph, arch: CIMArch, *, level=None,
 
     err = {t: 0 for t in graph.outputs}
     if use_executor:
-        from .executor import LoweringError, lower
+        from .executor import lower
         res = compiler.compile_graph(graph, arch, level=level,
                                      **compile_kwargs)
-        try:
-            t0 = time.time()
-            exe = lower(res.plan, res.program, params=p, faults=faults)
-            packed = exe.pack(weights)
-            t1 = time.time()
-            batched = {name: np.stack([x[name] for x in inputs])
-                       for name in graph.inputs}
-            outs = exe.run_batch(batched, packed=packed, shifts=shifts)
-            t2 = time.time()
-            for i in range(batch):
-                for t in graph.outputs:
-                    d = np.abs(np.asarray(outs[t][i], np.int64)
-                               - refs[i][t].astype(np.int64))
-                    err[t] = max(err[t], int(d.max()) if d.size else 0)
-            return VerifyReport(graph=graph.name, arch=arch.name,
-                                batch=batch, max_abs_err=err,
-                                lower_s=t1 - t0, run_s=t2 - t1)
-        except LoweringError:
-            pass       # fast path unavailable: verify op by op below
+        t0 = time.time()
+        exe = lower(res.plan, res.program, params=p, faults=faults)
+        packed = exe.pack(weights)
+        t1 = time.time()
+        batched = {name: np.stack([x[name] for x in inputs])
+                   for name in graph.inputs}
+        outs = exe.run_batch(batched, packed=packed, shifts=shifts)
+        t2 = time.time()
+        for i in range(batch):
+            for t in graph.outputs:
+                d = np.abs(np.asarray(outs[t][i], np.int64)
+                           - refs[i][t].astype(np.int64))
+                err[t] = max(err[t], int(d.max()) if d.size else 0)
+        return VerifyReport(graph=graph.name, arch=arch.name,
+                            batch=batch, max_abs_err=err,
+                            lower_s=t1 - t0, run_s=t2 - t1)
 
     res = compiler.compile_graph(graph, arch, level=level, expand=True,
                                  **compile_kwargs)

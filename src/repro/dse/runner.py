@@ -26,8 +26,9 @@ Execution model:
     reusing the caller's cache object so its memory layer stays live;
   * compile jobs with ``workers > 1`` are farmed to a process pool; each
     worker re-opens the cache directory (``memory=False`` — workers must
-    not grow resident memory) and entries are written atomically.  If
-    the host cannot fork, the pool degrades to the same per-job code
+    not grow resident memory) and entries are written atomically.  The
+    pool spawns its workers (never forks).  If the host cannot start
+    processes, the pool degrades to the same per-job code
     path serially.  Either way the caller's cache memory layer is
     dropped afterwards so freshly-written disk entries become visible.
 
@@ -282,8 +283,14 @@ def run_jobs(jobs: Iterable[EvalJob],
             cache_dir = str(cache.root) if cache is not None else None
             args = [(j, cache_dir) for j in compile_jobs]
             try:
+                import multiprocessing
                 from concurrent.futures import ProcessPoolExecutor
-                with ProcessPoolExecutor(max_workers=workers) as pool:
+                # spawn, not fork: the workers never touch JAX, but a
+                # parent that has opened an accelerator must not fork
+                # its runtime into children
+                ctx = multiprocessing.get_context("spawn")
+                with ProcessPoolExecutor(max_workers=workers,
+                                         mp_context=ctx) as pool:
                     results.extend(pool.map(_eval_job_worker, args,
                                             chunksize=1))
             except (OSError, ImportError):  # no processes: degrade serially

@@ -25,14 +25,15 @@ per-kernel capability.  Overrides exist at three levels:
     (the CI conformance legs run the same suite under each mode).
 
 Asking for an unsupported combination (``compiled`` on CPU) raises
-``KernelUnsupportedError`` — the executor maps that to ``LoweringError``
-so the serving stack's documented interpreter fallback keeps working.
+``KernelUnsupportedError``, which reaches the caller of the executor and
+of the serving stack unchanged: no route falls back to another.
 """
 from __future__ import annotations
 
 import contextlib
 import dataclasses
 import os
+import pathlib
 from typing import Dict, Optional, Tuple
 
 #: execution routes, in "fast on an accelerator" order
@@ -41,6 +42,8 @@ AUTO = "auto"
 
 _ENV_MODE = "REPRO_KERNEL_MODE"
 _ENV_PLATFORM = "REPRO_KERNEL_PLATFORM"
+_ENV_JAX_CACHE = "JAX_COMPILATION_CACHE_DIR"
+_DEFAULT_JAX_CACHE = pathlib.Path(__file__).resolve().parents[3] / ".jax_cache"
 
 
 class KernelUnsupportedError(RuntimeError):
@@ -214,6 +217,24 @@ def resolve(kernel: str, mode: Optional[str] = None,
             f"{kernel}: mode {want!r} is not supported on {platform!r} "
             f"(available: {avail})")
     return KernelRoute(kernel, platform, want, "explicitly requested")
+
+
+def enable_compile_cache() -> str:
+    """Turn on JAX's persistent compilation cache; return its directory.
+
+    Where ``JAX_COMPILATION_CACHE_DIR`` is set, JAX reads it itself and
+    the cache stays there.  Otherwise it goes to the fixed
+    ``<checkout>/.jax_cache``: the directory is part of the cache key, so
+    it must not move between runs.  This caches compiled XLA programs; the
+    CIM plan cache (``REPRO_COMPILE_CACHE_DIR``, ``dse.cache``) is another
+    thing.
+    """
+    import jax
+    path = os.environ.get(_ENV_JAX_CACHE)
+    if not path:
+        path = str(_DEFAULT_JAX_CACHE)
+        jax.config.update("jax_compilation_cache_dir", path)
+    return path
 
 
 def capability_matrix(platform: Optional[str] = None) -> Dict[str, Dict]:

@@ -12,8 +12,9 @@ TPU-native rather than a port of the analog array:
   * bit planes are laid out as leading non-tiled axes, pre-transposed by
     ops.py so the kernel body is pure batched ``dot_general`` — no
     in-kernel reshapes/transposes (TPU layouts stay trivial);
-  * row groups become the batch dim of an int8 x int8 -> int32 MXU batch
-    matmul; the ADC clamp is a VPU ``minimum`` between accumulations;
+  * row groups become the batch dim of an MXU batch matmul, int8 x int8
+    -> int32, or bf16 x bf16 -> f32 for 8-bit planes (ops._plane_dtype);
+    the ADC clamp is a VPU ``minimum`` on the int32 sum of each read;
   * block sizes keep the lane dim at 128 and the working set in VMEM
     (see ops.py block-size policy).
 
@@ -27,6 +28,12 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+
+
+def _acc_dtype(planes) -> jnp.dtype:
+    """int8 planes accumulate in int32 on the MXU; bf16 planes (an 8-bit
+    DAC or cell, see ops._plane_dtype) in float32, exact below 2^24."""
+    return jnp.int32 if planes.dtype == jnp.int8 else jnp.float32
 
 
 def _kernel(xpg_ref, wsg_ref, out_ref, *, dac_bits: int, cell_bits: int,
@@ -47,9 +54,9 @@ def _kernel(xpg_ref, wsg_ref, out_ref, *, dac_bits: int, cell_bits: int,
             part = jax.lax.dot_general(
                 xg, wg,
                 dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.int32)        # (gb, bm, bc)
+                preferred_element_type=_acc_dtype(xg))  # (gb, bm, bc)
             # ADC saturation happens per analog read (per group)
-            part = jnp.minimum(part, adc_max)
+            part = jnp.minimum(part.astype(jnp.int32), adc_max)
             shift = p * dac_bits + s * cell_bits
             acc = acc + (part.sum(axis=0) << shift)
 
@@ -115,8 +122,8 @@ def _tiles_kernel(xpg_ref, wsg_ref, out_ref, *, dac_bits: int,
             part = jax.lax.dot_general(
                 xg, wg,
                 dimension_numbers=(((2,), (1,)), ((0,), (0,))),
-                preferred_element_type=jnp.int32)        # (gb, bm, bc)
-            part = jnp.minimum(part, adc_max)
+                preferred_element_type=_acc_dtype(xg))  # (gb, bm, bc)
+            part = jnp.minimum(part.astype(jnp.int32), adc_max)
             shift = p * dac_bits + s * cell_bits
             acc = acc + (part.sum(axis=0) << shift)
 

@@ -73,13 +73,34 @@ def _pad_to(x: jnp.ndarray, axis: int, mult: int) -> jnp.ndarray:
 
 def _block_policy(m: int, c: int, r_groups: int, pr: int):
     """Pick (block_m, block_c, groups_per_block) with the lane dim at 128
-    and the VMEM working set bounded (~2 MiB of int8 planes)."""
+    and the VMEM working set bounded (~2 MiB of int8 planes, twice that
+    for bf16 planes: tests/test_tpu_compile.py checks both fit)."""
     block_m = 128 if m >= 128 else max(8, 1 << (m - 1).bit_length())
     block_c = 128 if c >= 128 else max(128, c)   # pad small C up to a lane
     gb = max(1, min(r_groups, max(1, 512 // max(pr, 1))))
     while r_groups % gb:
         gb -= 1
     return block_m, block_c, gb
+
+
+def _plane_dtype(params: CimMvmParams, pr: int):
+    """MXU operand dtype of the kernel's bit planes.
+
+    ``int8`` when every plane fits it (at most 7 bits).  Wider planes
+    (an 8-bit DAC or cell) go to ``bfloat16`` with float32 accumulation:
+    planes of up to 8 bits are exact in bf16, and one group's analog sum,
+    at most ``pr * (2^dac - 1) * (2^cell - 1)``, is exact in f32 below
+    2^24.  Mosaic on TPU takes no int32 x int32 matmul.
+    """
+    if max(params.dac_bits, params.cell_bits) <= 7:
+        return jnp.int8
+    vmax = pr * ((1 << params.dac_bits) - 1) * ((1 << params.cell_bits) - 1)
+    if max(params.dac_bits, params.cell_bits) > 8 or vmax >= 1 << 24:
+        raise backend.KernelUnsupportedError(
+            f"cim_mvm Pallas kernel: planes of {params.dac_bits}/"
+            f"{params.cell_bits} bits over parallel_row={pr} overflow the "
+            "exact bf16/f32 MXU range; use mode='xla'")
+    return jnp.bfloat16
 
 
 def _resolve_route(kernel: str, mode: Optional[str], use_kernel,
@@ -136,9 +157,7 @@ def _cim_mvm_impl(x_u: jnp.ndarray, w_u: jnp.ndarray, params: CimMvmParams,
     ws = ref.bit_planes(w_u, params.weight_bits, params.cell_bits)  # (S,R',C)
     P, S = xp.shape[0], ws.shape[0]
 
-    # int8 planes when they fit (MXU-native); int32 otherwise
-    plane_dtype = jnp.int8 if max(params.dac_bits, params.cell_bits) <= 7 \
-        else jnp.int32
+    plane_dtype = _plane_dtype(params, pr)
 
     block_m, block_c, gb = _block_policy(m, c, n_groups, pr)
     # grouped layouts: (P,G,M,pr) and (S,G,pr,C), padded to the grid
@@ -177,8 +196,7 @@ def _cim_mvm_tiles_impl(x_u: jnp.ndarray, w_u: jnp.ndarray,
     xp = ref.bit_planes(x_u, params.act_bits, params.dac_bits)   # (P,T,M,R')
     ws = ref.bit_planes(w_u, params.weight_bits, params.cell_bits)
     P, S = xp.shape[0], ws.shape[0]
-    plane_dtype = jnp.int8 if max(params.dac_bits, params.cell_bits) <= 7 \
-        else jnp.int32
+    plane_dtype = _plane_dtype(params, pr)
 
     block_m, block_c, gb = _block_policy(m, c, n_groups, pr)
     # tile-major grouped layouts: (T,P,G,M,pr) and (T,S,G,pr,C)

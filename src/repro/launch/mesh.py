@@ -12,14 +12,21 @@ from __future__ import annotations
 import jax
 
 
+def _mesh(shape, axes) -> jax.sharding.Mesh:
+    # Auto axes: the models shard through annotations, not the Explicit
+    # sharding-in-types that ``jax.make_mesh`` defaults to since jax 0.7
+    return jax.make_mesh(shape, axes,
+                         axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
+
+
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return _mesh(shape, axes)
 
 
 def make_host_mesh(model: int = 1) -> jax.sharding.Mesh:
     """Tiny mesh over the real host devices (tests / smoke runs)."""
     n = len(jax.devices())
     model = min(model, n)
-    return jax.make_mesh((n // model, model), ("data", "model"))
+    return _mesh((n // model, model), ("data", "model"))

@@ -4,9 +4,11 @@ The serving-side consumer of cimsim.executor: compile a workload for a
 CIM chip once, lower the meta-operator flow once, then serve request
 traffic by stacking queued inputs on the executor's batch axis — one
 device dispatch per batch instead of one interpreter walk per request.
-``use_executor=False`` keeps the op-by-op interpreter as a
-reference/fallback path (same outputs, orders of magnitude slower),
-which is also how the service is tested.
+``use_executor=False`` serves through the op-by-op interpreter
+instead (same outputs, orders of magnitude slower), which is also how
+the service is tested.  A program the executor cannot lower raises
+(``LoweringError``, ``KernelUnsupportedError``): the service never
+falls back to the interpreter on its own.
 
 Request/stats shapes live in ``serving.common`` (shared with the LM
 batch server and the multi-tenant fleet); ``serve_padded`` is the
@@ -74,15 +76,11 @@ class CimBatchService:
         kwargs = dict(compile_kwargs or {})
         kwargs.setdefault("level", level)
         if use_executor:
-            from ..cimsim.executor import LoweringError, lower
+            from ..cimsim.executor import lower
             res = compiler.compile_graph(graph, arch, cache=cache, **kwargs)
-            try:
-                self._exe = lower(res.plan, res.program, params=self.params)
-                self._packed = self._exe.pack(self.weights)
-            except LoweringError:
-                # flow has no bit-exact fast lowering: serve op by op
-                self.use_executor = use_executor = False
-        if not use_executor:
+            self._exe = lower(res.plan, res.program, params=self.params)
+            self._packed = self._exe.pack(self.weights)
+        else:
             from ..cimsim.functional import FunctionalSimulator
             res = compiler.compile_graph(graph, arch, cache=cache,
                                          expand=True, **kwargs)
@@ -94,7 +92,7 @@ class CimBatchService:
     def executor_stats(self):
         """The lowered executable's ``ExecutorStats`` (segments, streamed
         weight updates, resolved kernel route), or ``None`` when the
-        service degraded to the op-by-op interpreter."""
+        service runs the op-by-op interpreter (``use_executor=False``)."""
         return self._exe.stats if self.use_executor else None
 
     def serve(self, requests: List[CimRequest]) -> List[CimRequest]:

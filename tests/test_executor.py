@@ -161,16 +161,19 @@ def test_compile_and_verify_batched():
 
 
 def test_compile_and_verify_falls_back_on_lowering_error(monkeypatch):
-    """A flow the executor refuses (LoweringError) still verifies, op by
-    op — the documented fallback."""
+    """A flow the executor refuses raises ``LoweringError`` to the
+    caller; op-by-op verification runs only with use_executor=False."""
     from repro.cimsim import executor as executor_mod
 
     def refuse(*args, **kwargs):
         raise executor_mod.LoweringError("forced for test")
 
     monkeypatch.setattr(executor_mod, "lower", refuse)
-    rep = compile_and_verify(get_workload("tiny_mlp"), SMALL, batch=2)
-    assert rep.ok and rep.lower_s == 0.0    # interpreter path was used
+    with pytest.raises(executor_mod.LoweringError, match="forced"):
+        compile_and_verify(get_workload("tiny_mlp"), SMALL, batch=2)
+    rep = compile_and_verify(get_workload("tiny_mlp"), SMALL, batch=2,
+                             use_executor=False)
+    assert rep.ok and rep.lower_s == 0.0    # interpreter, as asked
 
 
 def test_lower_cache_reuses_executable():
@@ -254,6 +257,8 @@ def test_cim_batch_service_matches_interpreter():
 
 
 def test_cim_batch_service_falls_back_on_lowering_error(monkeypatch):
+    """``LoweringError`` reaches the service's caller; the interpreter
+    serves only with use_executor=False."""
     from repro.cimsim import executor as executor_mod
     from repro.serving.cim_service import CimBatchService, CimRequest
 
@@ -262,8 +267,10 @@ def test_cim_batch_service_falls_back_on_lowering_error(monkeypatch):
 
     monkeypatch.setattr(executor_mod, "lower", refuse)
     g = get_workload("tiny_mlp")
-    svc = CimBatchService(g, SMALL, max_batch=4)
-    assert not svc.use_executor            # degraded to the interpreter
+    with pytest.raises(executor_mod.LoweringError, match="forced"):
+        CimBatchService(g, SMALL, max_batch=4)
+    svc = CimBatchService(g, SMALL, max_batch=4, use_executor=False)
+    assert not svc.use_executor and svc.executor_stats is None
     reqs = [CimRequest(rid=i, inputs=make_input(g, i)) for i in range(2)]
     svc.serve(reqs)
     assert all(r.outputs is not None for r in reqs)

@@ -14,9 +14,9 @@ Three layers of evidence:
     platform without hypothesis — the cross-release, cross-backend
     contract;
   * registry regression tests: resolution precedence, forced
-    unsupported routes degrading to the documented interpreter
-    fallback bit-exactly, and multi-segment schedules staying on the
-    executor path (weight-update streaming, no fallback).
+    unsupported routes raising to the caller of the executor and the
+    serving stack (no silent interpreter run), and multi-segment
+    schedules staying on the executor path (weight-update streaming).
 
 ``accel``-marked cases exercise the genuinely compiled route and are
 auto-skipped off-accelerator (tests/conftest.py).
@@ -138,6 +138,30 @@ def test_golden_replay_compiled(path):
     np.testing.assert_array_equal(got, y)
 
 
+@pytest.mark.parametrize("dac,cell,pr,adc", [(8, 2, 128, 1), (8, 2, 16, 8),
+                                             (8, 8, 128, 4)],
+                         ids=["puma", "toy", "dac8-cell8"])
+def test_wide_planes_tiles_interpret_matches_xla(dac, cell, pr, adc):
+    """8-bit planes take the kernel's bf16/f32 MXU path; the Pallas body
+    stays bit-exact against the int32 oracle at the ADC limits."""
+    params = CimMvmParams(act_bits=8, weight_bits=8, dac_bits=dac,
+                          cell_bits=cell, parallel_row=pr, adc_bits=adc)
+    rng = np.random.default_rng(dac * 1000 + pr + adc)
+    x = rng.integers(0, 256, (3, 10, 2 * pr + 5)).astype(np.int32)
+    w = rng.integers(0, 256, (3, 2 * pr + 5, 20)).astype(np.int32)
+    _pairwise_equal("cim_mvm_tiles", cim_mvm_tiles, x, w, params)
+
+
+def test_plane_range_beyond_f32_refused():
+    """A group sum that f32 cannot hold exactly is refused, not rounded."""
+    params = CimMvmParams(dac_bits=8, cell_bits=8, parallel_row=512,
+                          adc_bits=8)
+    x = jnp.ones((1, 4, 512), jnp.int32)
+    w = jnp.ones((1, 512, 8), jnp.int32)
+    with pytest.raises(backend.KernelUnsupportedError, match="exact"):
+        cim_mvm_tiles(x, w, params, mode="interpret")
+
+
 # ---------------------------------------------- property sweeps
 
 def _pairwise_equal(kernel, fn, x, w, params):
@@ -244,9 +268,10 @@ def _force_unsupported(monkeypatch):
 
 
 def test_forced_unsupported_route_falls_back_bit_exact(monkeypatch):
-    """Registry says no -> LoweringError -> compile_and_verify takes the
-    documented interpreter fallback and still verifies bit-exactly."""
-    from repro.cimsim.executor import LoweringError, lower
+    """Registry says no -> KernelUnsupportedError reaches the callers of
+    ``lower`` and ``compile_and_verify``; the interpreter runs only when
+    asked for, and then still verifies bit-exactly."""
+    from repro.cimsim.executor import lower
     from repro.cimsim.functional import compile_and_verify
     from repro.core import compiler
     from repro.workloads import get_workload
@@ -254,14 +279,20 @@ def test_forced_unsupported_route_falls_back_bit_exact(monkeypatch):
     arch = _small_arch()
     _force_unsupported(monkeypatch)
     res = compiler.compile_graph(g, arch)
-    with pytest.raises(LoweringError, match="not supported"):
+    with pytest.raises(backend.KernelUnsupportedError, match="not supported"):
         lower(res.plan, res.program, cache=False)
-    rep = compile_and_verify(g, arch, batch=2)
-    assert rep.ok and rep.lower_s == 0.0     # interpreter path was used
+    with pytest.raises(backend.KernelUnsupportedError, match="not supported"):
+        compile_and_verify(g, arch, batch=2)
+    rep = compile_and_verify(g, arch, batch=2, use_executor=False)
+    assert rep.ok and rep.lower_s == 0.0     # interpreter, as asked
 
 
 def test_forced_unsupported_route_degrades_service(monkeypatch):
+    """An unsupportable kernel route fails the service and the fleet at
+    construction; only ``use_executor=False`` serves op by op, and its
+    outputs equal the executor's."""
     from repro.cimsim.functional import make_input
+    from repro.serving import CimFleet, TenantSpec
     from repro.serving.cim_service import CimBatchService, CimRequest
     from repro.workloads import get_workload
     g = get_workload("tiny_mlp")
@@ -269,8 +300,11 @@ def test_forced_unsupported_route_degrades_service(monkeypatch):
     reference = CimBatchService(g, arch, max_batch=4)
     assert reference.use_executor and reference.executor_stats is not None
     _force_unsupported(monkeypatch)
-    degraded = CimBatchService(g, arch, max_batch=4)
-    assert not degraded.use_executor
+    with pytest.raises(backend.KernelUnsupportedError):
+        CimBatchService(g, arch, max_batch=4)
+    with pytest.raises(backend.KernelUnsupportedError):
+        CimFleet([TenantSpec("mlp", g, traffic=1.0)], arch)
+    degraded = CimBatchService(g, arch, max_batch=4, use_executor=False)
     assert degraded.executor_stats is None
     reqs_a = [CimRequest(rid=i, inputs=make_input(g, i)) for i in range(3)]
     reqs_b = [CimRequest(rid=i, inputs=make_input(g, i)) for i in range(3)]
