@@ -314,14 +314,9 @@ class LoweredExecutable:
         #: first dispatch closes the compile→dispatch flow arrow
         self._flow_key: Optional[str] = None
         self._flow_done = False
-        #: host seconds spent packing each segment's pool payload on the
-        #: last streamed ``pack`` (the per-segment weight-programming
-        #: wall time — the only per-segment host cost that exists, since
-        #: the jitted trace stays one program)
-        self._seg_pack_s: List[float] = []
         #: bound metric instruments for the dispatch hot path, cached
         #: per registry identity so a dispatch pays attribute access +
-        #: a float add instead of four label-key constructions
+        #: a float add instead of two label-key constructions
         self._prof: Optional[tuple] = None
         self._disp_span = f"dispatch:{self.graph.name}"
         self._ox = 1 << (self.params.act_bits - 1)
@@ -521,28 +516,16 @@ class LoweredExecutable:
         order — the payloads the traced segment-boundary swaps write
         into the pool buffers.
         """
-        reg = obs_metrics.active()
-        tr = obs_trace.get_trace()
-        if reg is None and tr is None:
-            return self._pack_impl(weights)
-        t0 = time.perf_counter()
-        packed = self._pack_impl(weights)
-        dt = time.perf_counter() - t0
-        nbytes = _packed_nbytes(packed)
         name = self.graph.name
-        if reg is not None:
-            reg.counter("executor_packs_total", workload=name).inc()
-            reg.counter("executor_pack_bytes_total",
-                        workload=name).inc(nbytes)
-            reg.histogram("executor_pack_s").observe(dt)
-            for si, s in enumerate(self._seg_pack_s):
-                reg.histogram("executor_segment_pack_s",
-                              segment=si).observe(s)
-        if tr is not None:
-            tr.complete(obs_trace.EXECUTOR_TRACK, name, f"pack:{name}",
-                        "executor", obs_trace.now_s() - dt, dt,
-                        bytes=int(nbytes), segments=self._n_segments,
-                        streamed=self._stream)
+        t0 = time.perf_counter()
+        with obs_trace.span("cim.executor.pack", obs_trace.EXECUTOR_TRACK,
+                            name, event=f"pack:{name}", cat="executor",
+                            segments=self._n_segments,
+                            streamed=self._stream) as sp:
+            packed = self._pack_impl(weights)
+            if sp:
+                sp.args["bytes"] = _packed_nbytes(packed)
+        obs_metrics.observe("executor_pack_s", time.perf_counter() - t0)
         return packed
 
     def _pack_impl(self, weights: Dict[str, np.ndarray]) -> Dict[str, Any]:
@@ -556,9 +539,7 @@ class LoweredExecutable:
                                      f"{(cp.r, cp.c)}")
                 mats[name] = w
             segs: List[Dict[str, Any]] = []
-            self._seg_pack_s = []
             for si in range(self._n_segments):
-                t_seg = time.perf_counter()
                 entry = {}
                 for (seg, key), layout in self._seg_layout.items():
                     if seg != si:
@@ -576,7 +557,6 @@ class LoweredExecutable:
                              for name, span in layout])
                     entry[key] = jnp.asarray(tiles + self._ow)   # unsigned
                 segs.append(entry)
-                self._seg_pack_s.append(time.perf_counter() - t_seg)
             return {"segs": segs}
         packed: Dict[str, Any] = {}
         for name, cp in self._plans.items():
@@ -639,22 +619,31 @@ class LoweredExecutable:
         batch axis.  Pass ``packed=self.pack(weights)`` to amortize
         weight packing across calls.
 
-        Profiling happens here, at the dispatch boundary — the jitted
-        trace stays one program, so per-segment device times do not
-        exist to measure; the whole dispatch (which the trailing
-        ``np.asarray`` synchronizes) is the honest timing unit.
-        Disabled telemetry costs two ``is None`` checks.
+        Profiling happens here, at the dispatch boundary, and inside it
+        at the host's three steps: ``cim.executor.put`` (shifts and
+        inputs to the device), ``cim.executor.run`` (the jitted program,
+        until its outputs are ready) and ``cim.executor.fetch`` (outputs
+        to the host).  The jitted trace stays one program, so per-node
+        device times come from the profiler's device trace, where the
+        ops carry each node's ``jax.named_scope``.  Disabled telemetry
+        costs two ``is None`` checks here and one per step.
         """
         reg = obs_metrics.active()
-        tr = obs_trace.get_trace()
-        if reg is None and tr is None:
+        if reg is None and not obs_trace.spans_on():
             return self._run_batch_impl(inputs, weights, shifts,
                                         packed=packed)
-        t0 = time.perf_counter()
-        out = self._run_batch_impl(inputs, weights, shifts, packed=packed)
-        dt = time.perf_counter() - t0
-        n = int(next(iter(out.values())).shape[0]) if out else 0
+        n = int(next(iter(inputs.values())).shape[0]) if inputs else 0
         name = self.graph.name
+        t0 = time.perf_counter()
+        with obs_trace.span("cim.executor.dispatch",
+                            obs_trace.EXECUTOR_TRACK, name,
+                            event=self._disp_span, cat="executor", batch=n,
+                            route=self.route.mode,
+                            segments=self._n_segments,
+                            swaps=self.stats.swaps) as sp:
+            out = self._run_batch_impl(inputs, weights, shifts,
+                                       packed=packed)
+        dt = time.perf_counter() - t0
         if reg is not None:
             prof = self._prof
             if prof is None or prof[0] is not reg:
@@ -662,44 +651,47 @@ class LoweredExecutable:
                     reg,
                     reg.counter("executor_dispatches_total",
                                 route=self.route.mode),
-                    reg.counter("executor_requests_total", workload=name),
-                    reg.counter("executor_swaps_total", workload=name),
                     reg.histogram("executor_dispatch_s",
                                   route=self.route.mode))
             prof[1].inc()
-            prof[2].inc(n)
-            if self.stats.swaps:
-                prof[3].inc(self.stats.swaps)
-            prof[4].observe(dt)
-        if tr is not None:
-            now = obs_trace.now_s()
-            tr.complete(obs_trace.EXECUTOR_TRACK, name, self._disp_span,
-                        "executor", now - dt, dt, batch=n,
-                        route=self.route.mode, segments=self._n_segments,
-                        swaps=self.stats.swaps)
-            if self._flow_key is not None and not self._flow_done:
-                # close the compile→dispatch arrow inside this span
-                self._flow_done = True
-                tr.flow_end(obs_trace.EXECUTOR_TRACK, name, "artifact",
-                            "flow", now - dt / 2,
-                            flow_id=int(self._flow_key[:12], 16),
-                            key=self._flow_key[:12])
+            prof[2].observe(dt)
+        tr = obs_trace.get_trace()
+        if tr is not None and sp and self._flow_key is not None \
+                and not self._flow_done:
+            # close the compile→dispatch arrow inside this span
+            self._flow_done = True
+            tr.flow_end(obs_trace.EXECUTOR_TRACK, name, "artifact",
+                        "flow", sp.ts_s + sp.dur_s / 2,
+                        flow_id=int(self._flow_key[:12], 16),
+                        key=self._flow_key[:12])
         return out
 
     def _run_batch_impl(self, inputs, weights=None, shifts=None, *,
                         packed=None) -> Dict[str, np.ndarray]:
+        import jax
         import jax.numpy as jnp
         if packed is None:
             if weights is None:
                 raise ValueError("need weights=... or packed=...")
             packed = self.pack(weights)
         shifts = shifts or {}
-        sh = {name: jnp.int32(shifts.get(name, 0))
-              for name in self._shift_names}
-        xs = {name: jnp.asarray(np.asarray(v), jnp.int32)
-              for name, v in inputs.items()}
-        out = self._jit(packed, sh, xs)
-        return {name: np.asarray(v) for name, v in out.items()}
+        span, track, wl = obs_trace.span, obs_trace.EXECUTOR_TRACK, \
+            self.graph.name
+        # with spans on, put and run end on the device's readiness, so
+        # that each step's span holds its own device time
+        with span("cim.executor.put", track, wl) as sp:
+            sh = {name: jnp.int32(shifts.get(name, 0))
+                  for name in self._shift_names}
+            xs = {name: jnp.asarray(np.asarray(v), jnp.int32)
+                  for name, v in inputs.items()}
+            if sp:
+                jax.block_until_ready((sh, xs))
+        with span("cim.executor.run", track, wl) as sp:
+            out = self._jit(packed, sh, xs)
+            if sp:
+                jax.block_until_ready(out)
+        with span("cim.executor.fetch", track, wl):
+            return {name: np.asarray(v) for name, v in out.items()}
 
     # -- the traced program ----------------------------------------------
     def _swap_chain(self, segs):
@@ -720,21 +712,29 @@ class LoweredExecutable:
         return states
 
     def _forward(self, packed, shifts, inputs):
-        pools = self._swap_chain(packed["segs"]) if self._stream else None
+        """The traced program.  Each node's ops carry the node's name as
+        a ``jax.named_scope`` (and a CIM node's, its phase: ``im2col``,
+        ``gemm``, ``requant``), which the profiler's device trace shows
+        as each op's name path; the scopes are metadata only."""
+        import jax
+        pools = None
+        if self._stream:
+            with jax.named_scope("swap"):
+                pools = self._swap_chain(packed["segs"])
         tensors: Dict[str, Any] = dict(inputs)
         for node in self.graph.nodes:
             xs = [tensors[t] for t in node.inputs]
-            if node.is_cim:
-                pw = None if self._stream else packed[node.name]
-                tensors[node.outputs[0]] = self._cim(node, xs[0], pw,
-                                                     shifts[node.name],
-                                                     pools)
-            elif node.op_type == "Split":
-                for name, part in zip(node.outputs,
-                                      self._split(node, xs[0])):
-                    tensors[name] = part
-            else:
-                tensors[node.outputs[0]] = self._dcom(node, xs, shifts)
+            with jax.named_scope(node.name):
+                if node.is_cim:
+                    pw = None if self._stream else packed[node.name]
+                    tensors[node.outputs[0]] = self._cim(
+                        node, xs[0], pw, shifts[node.name], pools)
+                elif node.op_type == "Split":
+                    for name, part in zip(node.outputs,
+                                          self._split(node, xs[0])):
+                        tensors[name] = part
+                else:
+                    tensors[node.outputs[0]] = self._dcom(node, xs, shifts)
         return {t: tensors[t] for t in self.graph.outputs}
 
     def _rows(self, node: Node, x):
@@ -750,9 +750,19 @@ class LoweredExecutable:
         return x[:, None, :] if cp.vector_in else x
 
     def _cim(self, node: Node, x, pw, sh, pools=None):
+        import jax
+        cp = self._plans[node.name]
+        with jax.named_scope("im2col"):
+            rows = self._rows(node, x)                 # (N, M, R)
+        with jax.named_scope("gemm"):
+            acc = self._mvm(node, rows, pw, pools)     # (N, M, C)
+        with jax.named_scope("requant"):
+            return self._requant(cp, acc, sh)
+
+    def _mvm(self, node: Node, rows, pw, pools):
+        """(N, M, C) int32 accumulator of one CIM node's crossbar reads."""
         import jax.numpy as jnp
         cp = self._plans[node.name]
-        rows = self._rows(node, x)                     # (N, M, R)
         n, m, _ = rows.shape
         if cp.exact:
             if "hi" in pw:
@@ -812,6 +822,14 @@ class LoweredExecutable:
                 acc = acc.at[:, col_idx].add(
                     jnp.moveaxis(y, 0, 1).reshape(n * m, -1))
             acc = acc.reshape(n, m, cp.c)
+        return acc
+
+    @staticmethod
+    def _requant(cp: _CimPlan, acc, sh):
+        """Shift, clip to int8 range, and lay the output out as the
+        node's tensor."""
+        import jax.numpy as jnp
+        n = acc.shape[0]
         y = jnp.clip(acc >> sh, -128, 127).astype(jnp.int32)
         if cp.conv_out is not None:
             cout, oh, ow = cp.conv_out
@@ -953,18 +971,17 @@ def lower(plan: SchedulePlan, program: Program,
             _LOWER_CACHE.move_to_end(key)
             obs_metrics.count("executor_lower_cache_hits_total")
             return hit
+    name = plan.graph.name
     t0 = time.perf_counter()
-    exe = LoweredExecutable(plan, program, params, route=route,
-                            stream=streamed, faults=faults)
-    dt = time.perf_counter() - t0
+    with obs_trace.span("cim.executor.lower", obs_trace.EXECUTOR_TRACK,
+                        name, event=f"lower:{name}", cat="executor",
+                        route=route.mode, segments=len(plan.segments),
+                        streamed=streamed):
+        exe = LoweredExecutable(plan, program, params, route=route,
+                                stream=streamed, faults=faults)
     obs_metrics.count("executor_lowerings_total")
-    obs_metrics.observe("executor_lower_s", dt)
-    tr = obs_trace.get_trace()
-    if tr is not None:
-        tr.complete(obs_trace.EXECUTOR_TRACK, plan.graph.name,
-                    f"lower:{plan.graph.name}", "executor",
-                    obs_trace.now_s() - dt, dt, route=route.mode,
-                    segments=len(plan.segments), streamed=streamed)
+    obs_metrics.observe("executor_lower_s", time.perf_counter() - t0)
+    if obs_trace.get_trace() is not None:
         # remember the compile key so the first dispatch can close the
         # compile→dispatch flow arrow (ids match compile_graph's start)
         exe._flow_key = (key[0] if key is not None

@@ -264,6 +264,17 @@ def compile_graph(
     if not arch.mode.allows(level):
         raise ValueError(mode_error(arch, level))
 
+    with obs_trace.span("cim.compile", obs_trace.COMPILER_TRACK,
+                        graph.name, event=f"compile:{graph.name}",
+                        cat="compile", level=level.value) as sp:
+        return _compile(graph, arch, level, sp, use_pipeline=use_pipeline,
+                        use_duplication=use_duplication, binding=binding,
+                        expand=expand, cache=cache)
+
+
+def _compile(graph, arch, level, sp, *, use_pipeline, use_duplication,
+             binding, expand, cache) -> CompileResult:
+    """``compile_graph``'s body, inside its span ``sp``."""
     t0 = time.perf_counter()
     cache = cache if cache is not None else _COMPILE_CACHE
     key = compile_key(graph, arch, level=level, use_pipeline=use_pipeline,
@@ -272,7 +283,7 @@ def compile_graph(
     if cache is not None:
         hit = cache.get(key)
         if hit is not None:    # schema-2 entries are stored with key set
-            _note_compile(graph, arch, level, key, cached=True,
+            _note_compile(graph, arch, level, key, sp, cached=True,
                           wall_s=time.perf_counter() - t0, plan=hit.plan)
             return hit
 
@@ -308,35 +319,34 @@ def compile_graph(
     result = CompileResult(plan=plan, program=program, key=key)
     if cache is not None:
         cache.put(key, result)
-    _note_compile(graph, arch, level, key, cached=False,
+    _note_compile(graph, arch, level, key, sp, cached=False,
                   wall_s=time.perf_counter() - t0, plan=plan)
     return result
 
 
-def _note_compile(graph, arch, level, key, *, cached, wall_s, plan) -> None:
-    """Telemetry for one ``compile_graph`` return (hit or fresh build).
+def _note_compile(graph, arch, level, key, sp, *, cached, wall_s,
+                  plan) -> None:
+    """Telemetry for one ``compile_graph`` return (hit or fresh build),
+    made inside the compile's span ``sp``, which gains its last args.
 
-    Disabled telemetry costs two ``is None`` checks and one list
-    truthiness test; the span is drawn back from "now" so the compile
-    occupies its real wall interval on the compiler track.  The flow
-    start seeds the compile→dispatch arrow the executor's first
-    dispatch of this artifact closes (ids derive from the compile key
-    prefix on both sides — see ``cimsim.executor.lower``).
+    Disabled telemetry costs a few ``is None`` checks and one list
+    truthiness test.  The flow start seeds the compile→dispatch arrow
+    the executor's first dispatch of this artifact closes (ids derive
+    from the compile key prefix on both sides — see
+    ``cimsim.executor.lower``).
     """
     reg = obs_metrics.active()
     if reg is not None:
         reg.counter("compiles_total", workload=graph.name,
                     cached=cached).inc()
         reg.histogram("compile_wall_s", cached=cached).observe(wall_s)
+    if sp:
+        sp.args.update(cached=cached, segments=len(plan.segments),
+                       key=key[:12])
     tr = obs_trace.get_trace()
     if tr is not None:
-        now = obs_trace.now_s()
-        tr.complete(obs_trace.COMPILER_TRACK, graph.name,
-                    f"compile:{graph.name}", "compile",
-                    now - wall_s, wall_s, level=level.value, cached=cached,
-                    segments=len(plan.segments), key=key[:12])
         tr.flow_start(obs_trace.COMPILER_TRACK, graph.name,
-                      "artifact", "flow", now - wall_s / 2,
+                      "artifact", "flow", obs_trace.now_s() - wall_s / 2,
                       flow_id=int(key[:12], 16), key=key[:12])
     obs_hooks.emit("compile.done", graph=graph.name, arch=arch.name,
                    key=key, cached=cached, wall_s=wall_s,

@@ -102,6 +102,7 @@ def cim_mvm_pallas(xpg: jnp.ndarray, wsg: jnp.ndarray, *, dac_bits: int,
         out_specs=pl.BlockSpec((block_m, block_c), lambda i, j, k: (i, j)),
         out_shape=jax.ShapeDtypeStruct((M, C), jnp.int32),
         interpret=interpret,
+        name="cim_mvm",
     )(xpg, wsg)
 
 
@@ -172,4 +173,5 @@ def cim_mvm_tiles_pallas(xpg: jnp.ndarray, wsg: jnp.ndarray, *,
                                lambda t, i, j, k: (t, i, j)),
         out_shape=jax.ShapeDtypeStruct((T, M, C), jnp.int32),
         interpret=interpret,
+        name="cim_mvm_tiles",
     )(xpg, wsg)

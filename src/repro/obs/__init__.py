@@ -11,6 +11,9 @@ Three pieces, one enablement story:
     makes it the process-wide sink the compiler, executor and DSE
     drivers emit spans to, each on its own Perfetto process row; hand
     the same recorder to a fleet's ``trace=`` for one merged timeline.
+    ``trace.span()`` is the one span entry point, and
+    ``trace.use_profiler()`` adds the JAX profiler's trace as a second
+    sink, on the device ops' clock.
   * :mod:`repro.obs.explain` — per-node compile provenance
     (``ExplainReport`` / ``explain_compile``; CLI in
     ``tools/explain.py``), fed by the :mod:`repro.obs.hooks` events

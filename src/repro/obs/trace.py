@@ -36,6 +36,14 @@ serving rows show the model's own accounting next to the host-side
 rows.  Event ``ts``/``dur`` are emitted in **microseconds** as the
 format requires.
 
+Spans on two sinks: :func:`span` is the stack's one span entry point.
+It records the complete event above on the installed recorder, and,
+with :func:`use_profiler` on, also opens a
+``jax.profiler.TraceAnnotation`` of the same name, so the span sits on
+the JAX profiler's host plane on the same clock as the device ops.
+With both sinks off it returns a shared no-op after one ``is None``
+check.
+
 Thread-safety: a recorder is plain mutable state owned by one thread;
 share one recorder across the tiers of one run, not across concurrent
 runs.
@@ -63,6 +71,8 @@ _REQUIRED = ("name", "ph", "ts", "pid", "tid")
 COMPILER_TRACK = "compiler"
 EXECUTOR_TRACK = "executor"
 DSE_TRACK = "dse"
+SERVING_TRACK = "serving"
+_RESERVED_TRACKS = (COMPILER_TRACK, EXECUTOR_TRACK, DSE_TRACK, SERVING_TRACK)
 
 
 def _us(t_s: float) -> float:
@@ -90,8 +100,7 @@ class TraceRecorder:
         if chip not in self._pids:
             pid = len(self._pids) + 1
             self._pids[chip] = pid
-            label = chip if chip in (COMPILER_TRACK, EXECUTOR_TRACK,
-                                     DSE_TRACK) else f"chip:{chip}"
+            label = chip if chip in _RESERVED_TRACKS else f"chip:{chip}"
             self.events.append({"name": "process_name", "ph": "M",
                                 "ts": 0, "pid": pid, "tid": 0,
                                 "args": {"name": label}})
@@ -113,9 +122,10 @@ class TraceRecorder:
 
     # -- emitters --------------------------------------------------------
     def complete(self, chip: str, tenant: str, name: str, cat: str,
-                 ts_s: float, dur_s: float, **args) -> None:
+                 ts_s: float, dur_s: float, /, **args) -> None:
         """One span (``ph: "X"``): starts at ``ts_s``, lasts ``dur_s``
-        (clock seconds; negative durations are clamped to 0)."""
+        (clock seconds; negative durations are clamped to 0).  ``args``
+        may use any key, ``tenant`` and ``name`` included."""
         self.events.append({
             "name": name, "cat": cat, "ph": "X",
             "ts": _us(ts_s), "dur": _us(max(0.0, dur_s)),
@@ -267,6 +277,17 @@ def load_trace(path: Union[str, Path]) -> dict:
 
 _TRACE: Optional[TraceRecorder] = None
 _T0: float = 0.0
+#: ``jax.profiler.TraceAnnotation`` while the profiler sink is on
+_ANNOTATION = None
+#: ``(recorder, annotation class)`` while either sink is on, else None:
+#: the one check a disabled :func:`span` makes
+_SINKS: Optional[tuple] = None
+
+
+def _update_sinks() -> None:
+    global _SINKS
+    _SINKS = None if _TRACE is None and _ANNOTATION is None \
+        else (_TRACE, _ANNOTATION)
 
 
 def install(recorder: Optional[TraceRecorder] = None) -> TraceRecorder:
@@ -278,6 +299,7 @@ def install(recorder: Optional[TraceRecorder] = None) -> TraceRecorder:
     global _TRACE, _T0
     _TRACE = recorder if recorder is not None else TraceRecorder()
     _T0 = time.perf_counter()
+    _update_sinks()
     return _TRACE
 
 
@@ -285,7 +307,101 @@ def uninstall() -> Optional[TraceRecorder]:
     """Remove the process-wide recorder (tracing off); returns it."""
     global _TRACE
     prev, _TRACE = _TRACE, None
+    _update_sinks()
     return prev
+
+
+def use_profiler(on: bool = True) -> None:
+    """Switch the profiler sink: while on, every :func:`span` also opens
+    a ``jax.profiler.TraceAnnotation``, which a running
+    ``jax.profiler.start_trace`` records on its host plane (and which
+    costs next to nothing while no profile is being taken)."""
+    global _ANNOTATION
+    if on:
+        from jax.profiler import TraceAnnotation
+        _ANNOTATION = TraceAnnotation
+    else:
+        _ANNOTATION = None
+    _update_sinks()
+
+
+def spans_on() -> bool:
+    """Whether :func:`span` records anywhere (either sink is on)."""
+    return _SINKS is not None
+
+
+class _NoSpan:
+    """The shared span of a process with both sinks off: does nothing,
+    and is false, so callers skip work only a live span needs."""
+
+    __slots__ = ()
+
+    def __enter__(self):
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        return False
+
+    def __bool__(self) -> bool:
+        return False
+
+
+_NO_SPAN = _NoSpan()
+
+
+class Span:
+    """One live span (see :func:`span`).  ``args`` may grow until the
+    span ends: the recorder's event carries what it holds then (the
+    profiler's annotation only what it held at the start).  After the
+    span, ``ts_s``/``dur_s`` give its start on the process clock and its
+    length.  A body that raises leaves no recorder event, as the
+    counters count only work that finished; the profiler's annotation,
+    opened at the start, still closes."""
+
+    __slots__ = ("name", "track", "thread", "event", "cat", "args",
+                 "ts_s", "dur_s", "_sinks", "_ann", "_t0")
+
+    def __init__(self, name, track, thread, event, cat, args, sinks):
+        self.name, self.track, self.thread = name, track, thread
+        self.event, self.cat, self.args = event, cat, args
+        self.ts_s = self.dur_s = 0.0
+        self._sinks = sinks
+        self._ann = None
+
+    def __enter__(self) -> "Span":
+        annotation = self._sinks[1]
+        if annotation is not None:
+            self._ann = annotation(self.name, **self.args)
+            self._ann.__enter__()
+        self._t0 = time.perf_counter()
+        return self
+
+    def __exit__(self, *exc) -> bool:
+        t1 = time.perf_counter()
+        if self._ann is not None:
+            self._ann.__exit__(*exc)
+        self.ts_s, self.dur_s = self._t0 - _T0, t1 - self._t0
+        recorder = self._sinks[0]
+        if recorder is not None and exc[0] is None:
+            recorder.complete(self.track, self.thread,
+                              self.event or self.name,
+                              self.cat or self.track, self.ts_s,
+                              self.dur_s, **self.args)
+        return False
+
+
+def span(name: str, track: str, thread: str, *,
+         event: Optional[str] = None, cat: Optional[str] = None, **args):
+    """A context manager timing one piece of work on every sink that is
+    on: the profiler annotation ``name`` (``cim.<tier>.<what>``) with
+    ``args``, and on the installed recorder a complete event ``event``
+    (default ``name``) of category ``cat`` (default ``track``) on row
+    ``track``/``thread``.  With both sinks off it returns the shared
+    no-op, which is false."""
+    sinks = _SINKS
+    if sinks is None:
+        return _NO_SPAN
+    return Span(name, track, thread, event, cat, args, sinks)
 
 
 def get_trace() -> Optional[TraceRecorder]:

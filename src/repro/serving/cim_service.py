@@ -34,6 +34,8 @@ from ..core import compiler
 from ..core.abstraction import CIMArch
 from ..core.graph import Graph
 from ..kernels.cim_mvm import CimMvmParams, cim_mvm_params
+from ..obs import metrics as obs_metrics
+from ..obs import trace as obs_trace
 from .common import CimRequest, ServiceStats  # noqa: F401  (re-export)
 
 
@@ -69,8 +71,18 @@ class CimBatchService:
         self.params = params or cim_mvm_params(arch)
         self.weights = weights if weights is not None \
             else make_weights(graph, seed)
-        self.shifts = shifts if shifts is not None else calibrate_shifts(
-            graph, self.weights, make_input(graph, seed), self.params)
+        if shifts is None:
+            # one reference forward pass on the host: timed, since at
+            # published input sizes it is a large share of set-up
+            t0 = time.perf_counter()
+            with obs_trace.span("cim.service.calibrate",
+                                obs_trace.SERVING_TRACK, graph.name):
+                shifts = calibrate_shifts(graph, self.weights,
+                                          make_input(graph, seed),
+                                          self.params)
+            obs_metrics.observe("service_calibrate_s",
+                                time.perf_counter() - t0)
+        self.shifts = shifts
         self.stats = ServiceStats()
         self._warmed: set = set()        # batch sizes already jit-traced
         kwargs = dict(compile_kwargs or {})
@@ -148,10 +160,12 @@ class CimBatchService:
         n = len(batch)
         pad = max(0, (pad_to or n) - n)
         stacked = {}
-        for name in self.graph.inputs:
-            rows = [np.asarray(r.inputs[name]) for r in batch]
-            rows += [rows[-1]] * pad      # pad-to-bucket: repeat last row
-            stacked[name] = np.stack(rows)
+        with obs_trace.span("cim.service.stack", obs_trace.SERVING_TRACK,
+                            self.graph.name):
+            for name in self.graph.inputs:
+                rows = [np.asarray(r.inputs[name]) for r in batch]
+                rows += [rows[-1]] * pad  # pad-to-bucket: repeat last row
+                stacked[name] = np.stack(rows)
         outs = self._exe.run_batch(stacked, packed=self._packed,
                                    shifts=self.shifts)
         for i, r in enumerate(batch):

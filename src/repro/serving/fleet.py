@@ -47,6 +47,8 @@ from typing import Dict, Iterable, List, Mapping, Optional, Sequence
 import numpy as np
 
 from ..core.abstraction import CIMArch
+from ..obs import metrics as obs_metrics
+from ..obs import trace as obs_trace
 from .batcher import DEFAULT_BUCKETS, DynamicBatcher
 from .common import CimRequest, ServiceStats
 from .engine import EnginePool
@@ -308,11 +310,13 @@ class CimFleet:
         """
         now = time.monotonic() if now is None else now
         done: List[CimRequest] = []
-        for name, batcher in self._batchers.items():
-            batch = batcher.next_batch(now, force=force)
-            if batch is None:
-                continue
-            done.extend(self._dispatch(name, batch, now))
+        with obs_trace.span("cim.fleet.step", obs_trace.SERVING_TRACK,
+                            self.chip):
+            for name, batcher in self._batchers.items():
+                batch = batcher.next_batch(now, force=force)
+                if batch is None:
+                    continue
+                done.extend(self._dispatch(name, batch, now))
         return done
 
     def drain(self, now: Optional[float] = None) -> List[CimRequest]:
@@ -337,21 +341,35 @@ class CimFleet:
 
     def _dispatch(self, name: str, batch, now: float) -> List[CimRequest]:
         engine = self.pool[name]
+        reqs = batch.requests
+        reg = obs_metrics.active()
+        if reg is not None:
+            reg.counter("fleet_requests_total", tenant=name).inc(len(reqs))
+            reg.counter("fleet_bucket_rows_total",
+                        tenant=name).inc(batch.bucket)
+            wait = reg.histogram("fleet_queue_wait_s", tenant=name)
+            for r in reqs:
+                wait.observe(now - r.arrival_s)
         # bounded deterministic retry: only the typed transient channel
         # is retried (no sleeps — the service clock is caller-driven);
         # exhaustion re-raises so permanent failures stay loud
-        for attempt in range(self.max_retries + 1):
-            try:
-                dt = engine.serve_padded(batch.requests, batch.bucket)
-                break
-            except TransientKernelError:
-                if attempt >= self.max_retries:
-                    raise
-                self.retries += 1
-                if self.trace is not None:
-                    self.trace.instant(self.chip, f"retry:{name}", "fault",
-                                       now, attempt=attempt + 1,
-                                       bucket=batch.bucket)
+        with obs_trace.span("cim.fleet.dispatch", obs_trace.SERVING_TRACK,
+                            self.chip, tenant=name, bucket=batch.bucket,
+                            n=len(reqs), reason=batch.reason,
+                            rid0=reqs[0].rid, rid1=reqs[-1].rid):
+            for attempt in range(self.max_retries + 1):
+                try:
+                    dt = engine.serve_padded(reqs, batch.bucket)
+                    break
+                except TransientKernelError:
+                    if attempt >= self.max_retries:
+                        raise
+                    self.retries += 1
+                    if self.trace is not None:
+                        self.trace.instant(self.chip, f"retry:{name}",
+                                           "fault", now,
+                                           attempt=attempt + 1,
+                                           bucket=batch.bucket)
         dt *= self.slowdown
         # steady-state estimate feeding the deadline-pressure policy
         prev = self._observed_s.get(name)

@@ -323,3 +323,184 @@ def test_cache_and_dse_counters_reach_scorecards(tmp_path, telemetry):
     metrics.disable()
     clean = search_scorecard(result, "tiny_mlp")
     assert not any(k.startswith("obs_") for k in clean.meta)
+
+
+# ------------------------------------------------------ spans, two sinks
+
+CNN = get_workload("tiny_cnn")
+
+
+class _Annotations:
+    """Stands in for ``jax.profiler.TraceAnnotation``: logs each span's
+    name and args, and its open/close order."""
+
+    def __init__(self):
+        self.log = []
+        outer = self
+
+        class Annotation:
+            def __init__(self, name, **args):
+                self.name, self.args = name, args
+                outer.log.append(("new", name, args))
+
+            def __enter__(self):
+                outer.log.append(("enter", self.name))
+                return self
+
+            def __exit__(self, *exc):
+                outer.log.append(("exit", self.name))
+                return False
+
+        self.cls = Annotation
+
+    @property
+    def names(self):
+        return [e[1] for e in self.log if e[0] == "new"]
+
+
+@pytest.fixture
+def profiler_sink(monkeypatch):
+    """The profiler sink on, with the annotation class recorded; off
+    again after the test."""
+    import jax
+    fake = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", fake.cls)
+    trace.use_profiler(True)
+    try:
+        yield fake
+    finally:
+        trace.use_profiler(False)
+
+
+def _serve_cnn(n=3, seed=0):
+    fleet = CimFleet([TenantSpec("cnn", CNN, traffic=1.0)], ISAAC,
+                     max_wait_s=0.0, seed=seed)
+    reqs = [CimRequest(rid=i, model="cnn", inputs=make_input(CNN, i))
+            for i in range(n)]
+    return fleet, fleet.serve(reqs, now=0.0)
+
+
+def test_span_off_is_the_shared_noop_and_records_nothing(monkeypatch):
+    import jax
+    assert not trace.spans_on()
+    a = trace.span("cim.x", trace.EXECUTOR_TRACK, "g", n=1)
+    b = trace.span("cim.y", trace.SERVING_TRACK, "h")
+    assert a is b and not a
+    with a as inner:
+        assert inner is a
+    # the served path opens no annotation and records no event
+    fake = _Annotations()
+    monkeypatch.setattr(jax.profiler, "TraceAnnotation", fake.cls)
+    _, done = _serve_cnn()
+    assert len(done) == 3 and fake.log == []
+    assert trace.get_trace() is None and metrics.active() is None
+
+
+def test_span_with_a_recorder_writes_the_event_complete_wrote():
+    tr = trace.install()
+    try:
+        with trace.span("cim.executor.pack", trace.EXECUTOR_TRACK, "g",
+                        event="pack:g", cat="executor", segments=1) as sp:
+            sp.args["bytes"] = 64
+    finally:
+        trace.uninstall()
+    assert sp and sp.dur_s >= 0.0
+    old = TraceRecorder()
+    old.complete(trace.EXECUTOR_TRACK, "g", "pack:g", "executor",
+                 sp.ts_s, sp.dur_s, bytes=64, segments=1)
+    assert tr.events == old.events
+    validate_chrome_trace(tr.to_dict())
+    assert not trace.spans_on()
+
+
+def test_spans_nest_on_both_sinks(profiler_sink):
+    tr = trace.install()
+    try:
+        with trace.span("cim.outer", trace.SERVING_TRACK, "c", tenant="t"):
+            with trace.span("cim.inner", trace.SERVING_TRACK, "c"):
+                pass
+    finally:
+        trace.uninstall()
+    assert profiler_sink.log == [
+        ("new", "cim.outer", {"tenant": "t"}), ("enter", "cim.outer"),
+        ("new", "cim.inner", {}), ("enter", "cim.inner"),
+        ("exit", "cim.inner"), ("exit", "cim.outer")]
+    spans = {ev["name"]: ev for ev in tr.events if ev["ph"] == "X"}
+    outer, inner = spans["cim.outer"], spans["cim.inner"]
+    assert outer["args"] == {"tenant": "t"} and outer["cat"] == "serving"
+    assert outer["ts"] <= inner["ts"]
+    assert inner["ts"] + inner["dur"] <= outer["ts"] + outer["dur"] + 1e-3
+
+
+def test_span_whose_body_raises_records_no_event(profiler_sink):
+    tr = trace.install()
+    try:
+        with trace.span("cim.outer", trace.EXECUTOR_TRACK, "g"):
+            with pytest.raises(ValueError):
+                with trace.span("cim.inner", trace.EXECUTOR_TRACK, "g"):
+                    raise ValueError("transient")
+    finally:
+        trace.uninstall()
+    assert [ev["name"] for ev in tr.events if ev["ph"] == "X"] == [
+        "cim.outer"]
+    assert profiler_sink.log[-2:] == [("exit", "cim.inner"),
+                                      ("exit", "cim.outer")]
+
+
+def test_served_path_spans_and_counters(profiler_sink):
+    executor.clear_lower_cache()
+    reg = metrics.enable()
+    try:
+        fleet, done = _serve_cnn(n=3)
+    finally:
+        metrics.disable()
+    assert len(done) == 3
+    names = profiler_sink.names
+    assert names.count("cim.service.calibrate") == 1
+    # 3 requests drain as one padded bucket of 4: warm, then timed
+    for name in ("cim.service.stack", "cim.executor.put",
+                 "cim.executor.run", "cim.executor.fetch",
+                 "cim.executor.dispatch"):
+        assert names.count(name) == 2, name
+    disp = [e for e in profiler_sink.log
+            if e[0] == "new" and e[1] == "cim.fleet.dispatch"]
+    assert [e[2] for e in disp] == [{"tenant": "cnn", "bucket": 4, "n": 3,
+                                     "reason": "age", "rid0": 0,
+                                     "rid1": 2}]
+    assert "cim.compile" in names and "cim.executor.lower" in names
+    snap = reg.snapshot()
+    assert snap["counters"]['fleet_requests_total{tenant="cnn"}'] == 3
+    assert snap["counters"]['fleet_bucket_rows_total{tenant="cnn"}'] == 4
+    wait = snap["histograms"]['fleet_queue_wait_s{tenant="cnn"}']
+    assert wait["count"] == 3 and wait["sum"] == 0.0
+    cal = snap["histograms"]["service_calibrate_s"]
+    assert cal["count"] == 1 and cal["sum"] > 0
+
+
+def test_every_sink_on_is_bitexact(profiler_sink):
+    executor.clear_lower_cache()
+    _, off = _serve_cnn(n=3, seed=1)
+    reg = metrics.enable()
+    tr = trace.install()
+    try:
+        executor.clear_lower_cache()
+        _, on = _serve_cnn(n=3, seed=1)
+    finally:
+        metrics.disable()
+        trace.uninstall()
+    assert len(tr) > 0 and len(reg) > 0 and profiler_sink.log
+    for a, b in zip(off, on):
+        for t in a.outputs:
+            np.testing.assert_array_equal(a.outputs[t], b.outputs[t])
+
+
+def test_device_ops_carry_node_and_phase_scopes():
+    import jax.numpy as jnp
+    res = compiler.compile_graph(CNN, ISAAC)
+    exe = executor.lower(res.plan, res.program)
+    packed = exe.pack(make_weights(CNN, 0))
+    sh = {name: jnp.int32(0) for name in exe._shift_names}
+    xs = {"input": jnp.zeros((2, 3, 8, 8), jnp.int32)}
+    hlo = exe._jit.lower(packed, sh, xs).as_text(debug_info=True)
+    for scope in ("conv1/im2col", "conv2/gemm", "fc/requant", "pool/"):
+        assert scope in hlo, scope
