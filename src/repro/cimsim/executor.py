@@ -181,25 +181,24 @@ class _CimPlan:
     buckets: List[_Bucket]
     vector_in: bool                  # unbatched input was 1-D
     conv_out: Optional[Tuple[int, int, int]] = None   # (cout, oh, ow)
-    im2col_idx: Optional[np.ndarray] = None           # (M, C*k*k) gather
-    pad: int = 0
+    window: Optional[Tuple[int, int, int]] = None     # conv (k, stride, pad)
     stream_groups: Tuple[_StreamGroup, ...] = ()      # streamed mode only
 
 
-def _im2col_indices(cin: int, h: int, w: int, k: int, stride: int,
-                    pad: int) -> np.ndarray:
-    """Gather indices turning a flattened padded (C,Hp,Wp) image into the
-    (H_out*W_out, C*k*k) patch matrix of functional.im2col."""
-    hp, wp = h + 2 * pad, w + 2 * pad
-    oh = (h + 2 * pad - k) // stride + 1
-    ow = (w + 2 * pad - k) // stride + 1
-    ci, di, dj = np.meshgrid(np.arange(cin), np.arange(k), np.arange(k),
-                             indexing="ij")
-    patch = (ci * hp * wp + di * wp + dj).reshape(-1)        # (C*k*k,)
-    ii, jj = np.meshgrid(np.arange(oh) * stride, np.arange(ow) * stride,
-                         indexing="ij")
-    base = (ii * wp + jj).reshape(-1)                        # (OH*OW,)
-    return (base[:, None] + patch[None, :]).astype(np.int32)
+def _patches(x, k: int, stride: int, pad: int):
+    """(N, C, H, W) -> functional.im2col's (N, OH*OW, C*k*k) patch matrix,
+    stacked from the k*k static strided slices of the padded input: no
+    index table, no gather (jnp's stepped indexing would lower to one)."""
+    import jax.numpy as jnp
+    from jax import lax
+    x = jnp.pad(x, ((0, 0), (0, 0), (pad, pad), (pad, pad)))
+    n, c, hp, wp = x.shape
+    taps = [lax.slice(x, (0, 0, di, dj), (n, c, hp - k + 1 + di,
+                                          wp - k + 1 + dj),
+                      (1, 1, stride, stride))
+            for di in range(k) for dj in range(k)]      # (N, C, OH, OW) each
+    cols = jnp.stack(taps, axis=2)                      # (N, C, k*k, OH, OW)
+    return cols.reshape(n, c * k * k, -1).transpose(0, 2, 1)
 
 
 def _pool_indices(h: int, w: int, k: int, stride: int, pad: int
@@ -435,12 +434,8 @@ class LoweredExecutable:
                       vector_in=len(self.graph.shapes[node.inputs[0]]) == 1,
                       stream_groups=stream_groups)
         if node.op_type == "Conv":
-            cin, h, w = self.graph.shapes[node.inputs[0]]
-            k = node.attrs["weight_shape"][2]
-            cp.pad = node.attrs.get("pad", 0)
-            cp.im2col_idx = _im2col_indices(cin, h, w, k,
-                                            node.attrs.get("stride", 1),
-                                            cp.pad)
+            cp.window = (node.attrs["weight_shape"][2],
+                         node.attrs.get("stride", 1), node.attrs.get("pad", 0))
             cout = node.attrs["weight_shape"][0]
             oh, ow = self.graph.shapes[node.outputs[0]][1:]
             cp.conv_out = (cout, oh, ow)
@@ -580,10 +575,11 @@ class LoweredExecutable:
                 if cp.r <= _F32_SPLIT_MAX_R and self.params.act_bits <= 8 \
                         and self.params.weight_bits <= 8:
                     # split-plane GEMM: w = 16*w_hi + w_lo with w_hi in
-                    # [-8,7], w_lo in [0,15]; each f32 partial product sum
-                    # stays under 2^24 so the fast float GEMM is exact
-                    packed[name] = {"hi": jnp.asarray((w >> 4), jnp.float32),
-                                    "lo": jnp.asarray((w & 15), jnp.float32)}
+                    # [-8,7], w_lo in [0,15]; planes and int8 activations
+                    # are exact in bf16, and each f32 partial product sum
+                    # stays under 2^24, so the fast float GEMM is exact
+                    packed[name] = {"hi": jnp.asarray((w >> 4), jnp.bfloat16),
+                                    "lo": jnp.asarray((w & 15), jnp.bfloat16)}
                 else:
                     packed[name] = {"w": jnp.asarray(w)}
                 continue
@@ -739,20 +735,19 @@ class LoweredExecutable:
 
     def _rows(self, node: Node, x):
         """(N, windows, R) MVM input rows (im2col for Conv)."""
-        import jax.numpy as jnp
         cp = self._plans[node.name]
-        if node.op_type == "Conv":
-            n = x.shape[0]
-            p = cp.pad
-            if p:
-                x = jnp.pad(x, ((0, 0), (0, 0), (p, p), (p, p)))
-            return x.reshape(n, -1)[:, cp.im2col_idx]
+        if cp.window is not None:
+            return _patches(x, *cp.window)
         return x[:, None, :] if cp.vector_in else x
 
     def _cim(self, node: Node, x, pw, sh, pools=None):
         import jax
         cp = self._plans[node.name]
         with jax.named_scope("im2col"):
+            if cp.exact and "hi" in pw:
+                # the split-plane GEMM's operand type, before the k*k
+                # times larger patch matrix exists
+                x = x.astype(pw["hi"].dtype)
             rows = self._rows(node, x)                 # (N, M, R)
         with jax.named_scope("gemm"):
             acc = self._mvm(node, rows, pw, pools)     # (N, M, C)
@@ -766,9 +761,10 @@ class LoweredExecutable:
         n, m, _ = rows.shape
         if cp.exact:
             if "hi" in pw:
-                xf = rows.astype(jnp.float32)
-                acc = ((xf @ pw["hi"]).astype(jnp.int32) << 4) \
-                    + (xf @ pw["lo"]).astype(jnp.int32)
+                hi, lo = (jnp.matmul(rows, pw[p],
+                                     preferred_element_type=jnp.float32)
+                          for p in ("hi", "lo"))
+                acc = (hi.astype(jnp.int32) << 4) + lo.astype(jnp.int32)
             else:
                 acc = jnp.matmul(rows, pw["w"],
                                  preferred_element_type=jnp.int32)

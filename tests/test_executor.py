@@ -6,10 +6,10 @@ import sys
 import numpy as np
 import pytest
 
-from repro.cimsim.executor import LoweredExecutable, lower
+from repro.cimsim.executor import LoweredExecutable, _patches, lower
 from repro.cimsim.functional import (FunctionalSimulator, calibrate_shifts,
-                                     compile_and_verify, make_input,
-                                     make_weights, simulate)
+                                     compile_and_verify, im2col,
+                                     make_input, make_weights, simulate)
 from repro.core import compiler
 from repro.core.abstraction import (CellType, ChipTier, CIMArch,
                                     ComputingMode, CoreTier, CrossbarTier)
@@ -35,7 +35,7 @@ SATURATING = SMALL.replace(name="test-sat",
 MODES = [ComputingMode.WLM, ComputingMode.XBM, ComputingMode.CM]
 
 
-def _both(graph, arch):
+def _both(graph, arch, stream="auto"):
     """(interpreter outputs, executor outputs, executable) for one cell."""
     params = cim_mvm_params(arch)
     weights = make_weights(graph, 0)
@@ -45,7 +45,7 @@ def _both(graph, arch):
     sim = FunctionalSimulator(res.plan, res.program, weights, shifts,
                               params=params)
     sim_out = sim.run(inputs)
-    exe = lower(res.plan, res.program, params=params)
+    exe = lower(res.plan, res.program, params=params, stream=stream)
     exe_out = exe.run(inputs, weights, shifts)
     return sim_out, exe_out, exe
 
@@ -76,6 +76,59 @@ def test_executor_matches_interpreter_saturating_adc(wl, mode):
     for t in g.outputs:
         np.testing.assert_array_equal(sim_out[t], exe_out[t])
     assert exe.stats.matmul_nodes == 0     # tile-batched oracle path
+
+
+def _strided_cnn():
+    """ResNet's strided conv shapes at a small input: a 7x7/2 pad 3
+    stem, a 3x3/2 pad 1 conv and a 1x1/2 pad 0 projection."""
+    from repro.core.graph import Graph, Node
+    nodes = [
+        Node("stem", "Conv", ["input"], ["stem.out"],
+             {"weight_shape": (4, 1, 7, 7), "stride": 2, "pad": 3}),
+        Node("relu1", "Relu", ["stem.out"], ["relu1.out"]),
+        Node("down", "Conv", ["relu1.out"], ["down.out"],
+             {"weight_shape": (8, 4, 3, 3), "stride": 2, "pad": 1}),
+        Node("relu2", "Relu", ["down.out"], ["relu2.out"]),
+        Node("proj", "Conv", ["relu2.out"], ["proj.out"],
+             {"weight_shape": (8, 8, 1, 1), "stride": 2, "pad": 0}),
+        Node("flatten", "Flatten", ["proj.out"], ["flat.out"]),
+        Node("fc", "Gemm", ["flat.out"], ["fc.out"],
+             {"weight_shape": (8, 5)}),
+    ]
+    return Graph("strided_cnn", nodes, {"input": (1, 8, 8)}, ["fc.out"])
+
+
+@pytest.mark.parametrize("stream", [False, True], ids=["packed", "streamed"])
+@pytest.mark.parametrize("arch", [SMALL, SATURATING],
+                         ids=["exact", "saturating"])
+@pytest.mark.parametrize("mode", MODES)
+def test_executor_matches_interpreter_strided_convs(arch, mode, stream):
+    """Every MVM route (exact matmul, tile buckets, streamed pool) on
+    convs with stride 2, k 7 and k 1, and pad 0."""
+    g = _strided_cnn()
+    sim_out, exe_out, exe = _both(g, arch.replace(mode=mode), stream)
+    np.testing.assert_array_equal(sim_out["fc.out"], exe_out["fc.out"])
+    assert exe.stats.streamed == stream
+    assert exe.stats.matmul_nodes == (4 if arch is SMALL and not stream
+                                      else 0)
+
+
+@pytest.mark.parametrize("batch", [1, 3])
+@pytest.mark.parametrize("cin,h,w,k,stride,pad", [
+    (3, 16, 16, 7, 2, 3),       # ResNet stem
+    (4, 8, 8, 3, 1, 1),
+    (4, 8, 8, 3, 2, 1),
+    (4, 8, 8, 1, 2, 0),         # projection shortcut
+    (2, 6, 9, 3, 1, 1),         # non-square
+    (2, 10, 8, 3, 2, 0),        # (h + 2*pad - k) not a multiple of stride
+])
+def test_patches_match_functional_im2col(cin, h, w, k, stride, pad, batch):
+    x = np.random.default_rng(k * 10 + stride).integers(
+        -128, 128, (batch, cin, h, w)).astype(np.int32)
+    got = np.asarray(_patches(x, k, stride, pad))
+    assert got.dtype == np.int32
+    for b in range(batch):
+        np.testing.assert_array_equal(got[b], im2col(x[b], k, stride, pad))
 
 
 def test_executor_batch_axis_consistency():

@@ -3,6 +3,7 @@ semantics, compile provenance coverage, and the merged Perfetto
 timeline (compiler + executor + DSE + fleet + fault events)."""
 import dataclasses
 import json
+import re
 
 import numpy as np
 import pytest
@@ -504,3 +505,5 @@ def test_device_ops_carry_node_and_phase_scopes():
     hlo = exe._jit.lower(packed, sh, xs).as_text(debug_info=True)
     for scope in ("conv1/im2col", "conv2/gemm", "fc/requant", "pool/"):
         assert scope in hlo, scope
+    # conv patches come from static slices: no index gather
+    assert not re.search(r'conv[^/"]*/im2col/[^"]*gather', hlo)
