@@ -1,4 +1,4 @@
-"""Plain int8 reference of a CIM-served CNN, written for the benchmark.
+"""Plain int8 reference of a CIM-served network, written for the benchmark.
 
 It imports nothing of the program under test.  A network is a list of
 nodes built by a module under ``bench/nets/``; each node is a dict with
@@ -6,6 +6,18 @@ nodes built by a module under ``bench/nets/``; each node is a dict with
 follow the CIM-MLC paper's verification setup (§4.1): int8 fake-quant
 activations and weights, exact int32 accumulation, and a per-node
 arithmetic right shift back to int8 that one calibration pass picks.
+
+This module defines the ops of ResNet (``conv``, ``fc``, ``relu``,
+``add``, ``maxpool``, ``gap``, ``flatten``).  A network module may bring
+ops of its own: it passes ``{op name: function}`` to ``NetBuilder.net``
+and appends their nodes with ``NetBuilder.node``; ``forward`` calls
+``function(node, inputs, ctx)`` with an ``OpContext``, whose ``shifted``
+and ``operand`` calibrate, requantize and (in the control) degrade the
+op exactly as they do ``conv`` and ``fc``.  A node that carries ``R``
+and ``C`` (and ``windows``, the rows it multiplies per inference) is a
+crossbar node, whatever its op: it gets seeded int8 weights (R, C) and
+its multiply-accumulates count in ``work.py``.  A node may state its
+own ``macs``, for a matmul that the ALU runs.
 
 A crossbar MVM (``cim_mvm``) follows the bit-sliced crossbar of §3.2.3:
 inputs and weights are offset-encoded to unsigned, the input is fed
@@ -22,12 +34,13 @@ from __future__ import annotations
 
 import math
 import zlib
-from typing import Dict, List, Optional, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Tuple
 
 import numpy as np
 from numpy.lib.stride_tricks import sliding_window_view
 
-CIM_OPS = ("conv", "fc")
+#: the ops this module defines; a network's own op table may not name one
+BUILTIN_OPS = ("conv", "fc", "relu", "add", "maxpool", "gap", "flatten")
 
 
 # -- network description ------------------------------------------------------
@@ -86,14 +99,32 @@ class NetBuilder:
         return self._add(dict(name=name, op="flatten", inputs=[tin],
                               output=out), (n,))
 
-    def net(self, output: str) -> dict:
+    def node(self, name, op, inputs, out_shape, output=None, **sizes) -> str:
+        """A node of any op, with its sizes and output shape; its output
+        tensor is ``<name>.out`` unless ``output`` names it."""
+        return self._add(dict(name=name, op=op, inputs=list(inputs),
+                              output=output or f"{name}.out", **sizes),
+                         out_shape)
+
+    def net(self, output: str, ops: Optional[Dict[str, Callable]] = None
+            ) -> dict:
+        """The network; ``ops`` are the network's own ops, by name, none
+        of them named like an op of this module."""
+        clash = sorted(set(ops or {}) & set(BUILTIN_OPS))
+        if clash:
+            raise ValueError(f"network ops {clash} shadow the reference's")
         return {"name": self.name, "input_shape": self.input_shape,
                 "nodes": self.nodes, "output": output,
-                "shapes": dict(self.shapes)}
+                "shapes": dict(self.shapes), "ops": dict(ops or {})}
+
+
+def is_crossbar(node: dict) -> bool:
+    """A crossbar node holds an int8 weight matrix of R rows, C columns."""
+    return "R" in node and "C" in node
 
 
 def cim_nodes(net: dict) -> List[dict]:
-    return [n for n in net["nodes"] if n["op"] in CIM_OPS]
+    return [n for n in net["nodes"] if is_crossbar(n)]
 
 
 # -- seeded data ----------------------------------------------------------------
@@ -190,6 +221,19 @@ def _maxpool(x: np.ndarray, k: int, stride: int, pad: int) -> np.ndarray:
     return win.max(axis=(3, 4))
 
 
+class OpContext(NamedTuple):
+    """What ``forward`` hands a network's own op beside its node and
+    inputs: the crossbar weights by node name; ``shifted(name, y)``, the
+    node's calibrated requantization of its accumulator ``y``;
+    ``operand(v)``, a crossbar operand at the pass's precision; the
+    crossbar parameters; and ``cim_mvm``."""
+    weights: Dict[str, np.ndarray]
+    shifted: Callable[[str, np.ndarray], np.ndarray]
+    operand: Callable[[np.ndarray], np.ndarray]
+    cim: dict
+    cim_mvm: Callable[[np.ndarray, np.ndarray, dict], np.ndarray]
+
+
 def forward(net: dict, weights: Dict[str, np.ndarray], x: np.ndarray,
             cim: dict, shifts: Optional[Dict[str, int]] = None,
             operand_bits: int = 8) -> Tuple[np.ndarray, Dict[str, int]]:
@@ -215,6 +259,8 @@ def forward(net: dict, weights: Dict[str, np.ndarray], x: np.ndarray,
         lim = 1 << (operand_bits - 1)
         return np.clip(np.round(v / step), -lim, lim - 1).astype(np.int64) * step
 
+    ctx = OpContext(weights, shifted, operand, cim, cim_mvm)
+    own_ops = net.get("ops", {})
     t: Dict[str, np.ndarray] = {"input": np.asarray(x, np.int64)}
     for n in net["nodes"]:
         xs = [t[i] for i in n["inputs"]]
@@ -238,6 +284,8 @@ def forward(net: dict, weights: Dict[str, np.ndarray], x: np.ndarray,
             y = xs[0].sum(axis=(1, 2), keepdims=True) // (h * w)
         elif op == "flatten":
             y = xs[0].reshape(-1)
+        elif op in own_ops:
+            y = own_ops[op](n, xs, ctx)
         else:
             raise ValueError(f"reference has no op {op!r}")
         t[n["output"]] = y

@@ -18,8 +18,10 @@ seeded weights; warm every bucket shape the mix uses; measure for
 ``--seconds``; check a seeded sample of the served outputs against the
 plain int8 reference (``reference.py``), bit for bit; print.  With
 ``--trace 1`` the window (at most ``TRACE_SECONDS``) runs under the JAX
-profiler and the result carries the per-layer metrics instead of the
-end-to-end ones.
+profiler, with the program's own spans on the profiler's clock and a
+fresh registry of its counters, and the result carries the per-layer
+metrics instead of the end-to-end ones.  With ``--trace 0`` neither the
+profiler nor the program's sinks are on.
 
 JAX's compile cache is kept in ``bench/.cache/jax`` and the CIM plan
 cache in ``bench/.cache/plans``, inside the checkout, so that only a
@@ -56,6 +58,8 @@ TRACE_SECONDS = 5.0
 sys.path.insert(0, str(BENCH))
 
 import reference  # noqa: E402
+import span_reduce  # noqa: E402
+import trace_reduce  # noqa: E402
 import traffic  # noqa: E402
 import work  # noqa: E402
 
@@ -109,6 +113,34 @@ class Spans:
             return contextlib.nullcontext()
         import jax
         return jax.profiler.TraceAnnotation(f"bench.{name}")
+
+
+@contextlib.contextmanager
+def program_sinks():
+    """The program's own spans (``cim.*``) on the profiler's host plane
+    and a fresh registry of its counters, for the traced window only;
+    yields the registry."""
+    from repro.obs import metrics as obs_metrics
+    from repro.obs import trace as obs_trace
+    reg = obs_metrics.enable()
+    obs_trace.use_profiler(True)
+    try:
+        yield reg
+    finally:
+        obs_trace.use_profiler(False)
+        obs_metrics.disable()
+
+
+def reduce_trace(trace_dir: str):
+    """(device numbers, program spans) of the traced window, each
+    ``None`` where the trace holds nothing for it; removes the trace."""
+    reduced = spans = None
+    path = trace_reduce.find_xplane(trace_dir)
+    if path:
+        reduced = trace_reduce.reduce(*trace_reduce.load(path))
+        spans = span_reduce.reduce(*span_reduce.load(path))
+    shutil.rmtree(trace_dir, ignore_errors=True)
+    return reduced, spans
 
 
 class CompileCounter:
@@ -251,13 +283,19 @@ class Loop:
         self.overshoot_s = max(self.overshoot_s, self.clock() - t)
 
     def report(self) -> str:
-        steps = sorted(e - s for s, e in self.dispatches) or [0.0]
-        gaps = [b[0] - a[1] for a, b in zip(self.dispatches,
-                                            self.dispatches[1:])]
+        spans = [(e - s, s) for s, e in self.dispatches] or [(0.0, 0.0)]
+        gaps = [(b[0] - a[1], a[1]) for a, b in zip(self.dispatches,
+                                                    self.dispatches[1:])]
+        gaps = gaps or [(0.0, 0.0)]
+        steps = sorted(d for d, _ in spans)
+        (step_max, step_at), (gap_max, gap_at) = max(spans), max(gaps)
         return (f"dispatch median {1e3 * steps[len(steps) // 2]:.3f} ms, "
-                f"longest {1e3 * steps[-1]:.3f} ms; longest gap between "
-                f"dispatches {1e3 * max(gaps, default=0.0):.3f} ms; longest "
-                f"sleep overshoot {1e3 * self.overshoot_s:.3f} ms")
+                f"mean {1e3 * sum(steps) / len(steps):.3f} ms, longest "
+                f"{1e3 * step_max:.3f} ms at {step_at:.3f} s; gaps between "
+                f"dispatches mean "
+                f"{1e3 * sum(d for d, _ in gaps) / len(gaps):.3f} ms, longest "
+                f"{1e3 * gap_max:.3f} ms at {gap_at:.3f} s; longest sleep "
+                f"overshoot {1e3 * self.overshoot_s:.3f} ms")
 
 
 class HostWatch:
@@ -381,7 +419,8 @@ def run_cell(cfg: dict, mix: dict, e2e: list, per_layer: list, *, seed: int,
     setup_s = time.perf_counter() - T_PROCESS
     host = HostWatch()
     counter.armed = True
-    with span("window"):
+    with program_sinks() if trace else contextlib.nullcontext() \
+            as registry, span("window"):
         loop = Loop(fleet, cfg, pool, span)
         handles = mix["process"].window(loop, mix, seed, seconds)
     counter.armed = False
@@ -409,13 +448,7 @@ def run_cell(cfg: dict, mix: dict, e2e: list, per_layer: list, *, seed: int,
     print(f"host in the window: {loop.report()}; {host_line}",
           file=sys.stderr)
 
-    reduced = None
-    if trace:
-        import trace_reduce
-        path = trace_reduce.find_xplane(trace_dir)
-        if path:
-            reduced = trace_reduce.reduce(*trace_reduce.load(path))
-        shutil.rmtree(trace_dir, ignore_errors=True)
+    reduced, spans = reduce_trace(trace_dir) if trace else (None, None)
 
     # the served outputs leave with the sample; the program's state goes
     sample = pick_sample(handles, cfg["check_requests"], seed)
@@ -445,7 +478,8 @@ def run_cell(cfg: dict, mix: dict, e2e: list, per_layer: list, *, seed: int,
         "latencies_ms": lat_ms, "queue_wait_ms": wait_ms,
         "batches": batches, "serve_s": serve_s, "hist": hist,
         "jit_warm_s": jit_warm_s, "setup_s": setup_s,
-        "net": net, "peaks": peaks, "trace": reduced,
+        "net": net, "peaks": peaks, "trace": reduced, "spans": spans,
+        "counters": registry.snapshot() if registry is not None else None,
     }
     metrics = {}
     for m in (per_layer if trace else e2e):
@@ -459,7 +493,9 @@ def run_cell(cfg: dict, mix: dict, e2e: list, per_layer: list, *, seed: int,
     if trace and reduced:
         device["busy_s"] = reduced["busy_s"]
         device["window_s"] = reduced["window_s"]
-        out["breakdown"] = reduced["breakdown"]
+        out["breakdown"] = dict(reduced["breakdown"])
+        if spans:
+            out["breakdown"].update(spans["breakdown"])
     print(f"reference check: {len(sample)} requests in {ref_s:.3f} s",
           file=sys.stderr)
     for k, (v, lim) in checks.items():

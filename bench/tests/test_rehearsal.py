@@ -33,8 +33,8 @@ POISSON = dict(run.traffic.load(BENCH / "traffic" / "poisson-38.json"),
 ISAAC = "isaac-baseline"
 
 
-def _cfg(arch, check_requests=4):
-    return {"network": "tiny_cnn", "sizes": {}, "arch": arch,
+def _cfg(arch, check_requests=4, network="tiny_cnn"):
+    return {"network": network, "sizes": {}, "arch": arch,
             "cim": dataclasses.asdict(cim_mvm_params(get_arch(arch))),
             "buckets": [1, 2, 4, 8], "max_wait_s": 0.002,
             "check_requests": check_requests}
@@ -42,24 +42,38 @@ def _cfg(arch, check_requests=4):
 
 PER_LAYER = {
     "backlog": ["dispatch_ms.offline", "device_idle_share.offline",
-                "step_mfu.offline"],
-    "poisson": ["queue_wait_ms.server", "dispatch_ms.server"],
+                "step_mfu.offline", "host_prep_ms.offline",
+                "device_wait_ms.offline", "fetch_ms.offline",
+                "idle_in_program_share.offline", "im2col_ms.offline",
+                "gemm_ms.offline"],
+    "poisson": ["queue_wait_ms.server", "dispatch_ms.server",
+                "batcher_wait_ms.server", "bucket_fill.server"],
 }
 
 
-def _metrics(kind):
+# readers of the program's host spans and of its counters: in both mixes
+# they read in a traced run and nothing in an untraced one
+HOST_SPANS = {"host_prep_ms.offline", "device_wait_ms.offline",
+              "fetch_ms.offline"}
+COUNTED = {"batcher_wait_ms.server", "bucket_fill.server"}
+DEVICE = {"device_idle_share.offline", "step_mfu.offline",
+          "idle_in_program_share.offline", "im2col_ms.offline",
+          "gemm_ms.offline"}
+
+
+def _metrics(kind, extra=()):
     e2e = ["setup_s", "inferences_per_s"] if kind == "backlog" \
         else ["setup_s", "p50_latency_ms", "p95_latency_ms"]
     per_layer = PER_LAYER[kind] + ["compile_s", "lower_pack_s", "jit_warm_s"]
-    return ([{"name": n, "unit": "-"} for n in e2e],
-            [{"name": n, "unit": "-"} for n in per_layer])
+    return ([{"name": n, "unit": "-"} for n in [*e2e, *extra]],
+            [{"name": n, "unit": "-"} for n in [*per_layer, *extra]])
 
 
 def _run(mix, trace=False, control_bits=8, seed=2 ** 31 + 7,
-         check_requests=4):
-    e2e, per_layer = _metrics(mix["kind"])
-    return run.run_cell(_cfg(ISAAC, check_requests), mix, e2e, per_layer,
-                        seed=seed,
+         check_requests=4, network="tiny_cnn", extra=()):
+    e2e, per_layer = _metrics(mix["kind"], sorted(set(extra)))
+    return run.run_cell(_cfg(ISAAC, check_requests, network), mix, e2e,
+                        per_layer, seed=seed,
                         seconds=1.0, trace=trace, require_tpu=False,
                         control_bits=control_bits)
 
@@ -86,13 +100,27 @@ def test_run_is_correct_and_well_formed(mix, capsys):
 
 @pytest.mark.parametrize("mix", [BACKLOG, POISSON], ids=["backlog", "poisson"])
 def test_traced_run_reports_per_layer_metrics(mix):
-    out = _run(mix, trace=True)
+    # both cells' readers of the program's spans and counters, in each mix
+    out = _run(mix, trace=True, extra=HOST_SPANS | COUNTED | DEVICE)
     assert out["correct"] is True
-    # the CPU has no device trace: only the host-side readers report
-    expect = {"compile_s", "lower_pack_s", "jit_warm_s"}
+    # the CPU has no device trace: only the readers of the harness's
+    # clock, the program's host spans and its counters report
+    expect = {"compile_s", "lower_pack_s", "jit_warm_s"} | HOST_SPANS | COUNTED
     expect |= {"dispatch_ms.offline"} if mix["kind"] == "backlog" else \
         {"dispatch_ms.server", "queue_wait_ms.server"}
     assert set(out["metrics"]) == expect
+    assert 0 < out["metrics"]["bucket_fill.server"]["value"] <= 100
+    assert out["metrics"]["batcher_wait_ms.server"]["value"] >= 0
+
+
+@pytest.mark.parametrize("mix", [BACKLOG, POISSON], ids=["backlog", "poisson"])
+def test_untraced_run_leaves_the_program_sinks_off(mix, monkeypatch):
+    def refuse():
+        raise AssertionError("program sinks opened in an untraced run")
+    monkeypatch.setattr(run, "program_sinks", refuse)
+    out = _run(mix, extra=HOST_SPANS | COUNTED)
+    assert out["correct"] is True
+    assert not (HOST_SPANS | COUNTED) & set(out["metrics"])
 
 
 def test_arrivals_are_one_set_in_seeded_orders():
@@ -111,10 +139,18 @@ def test_an_unknown_kind_is_refused(tmp_path):
         run.traffic.load(tmp_path / "bursty.json")
 
 
-def test_control_is_not_correct():
-    out = _run(BACKLOG, control_bits=control.CONTROL_BITS)
+@pytest.mark.parametrize("network", ["tiny_cnn", "tiny_mlp"])
+def test_control_is_not_correct(network):
+    out = _run(BACKLOG, control_bits=control.CONTROL_BITS, network=network)
     assert out["correct"] is False
     assert out["checks"]["max_abs_diff"]["value"] > 0
+
+
+def test_a_network_with_its_own_crossbar_op_is_correct():
+    """``tiny_mlp`` brings its crossbar op in its own file (``nets/``)."""
+    out = _run(BACKLOG, network="tiny_mlp")
+    assert out["correct"] is True and out["attempted"] > 0
+    assert out["checks"]["mismatched_requests"]["value"] == 0
 
 
 def _altered(batch_outputs):

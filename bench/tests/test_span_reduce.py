@@ -1,12 +1,10 @@
 """The program-span reduction on a hand-built trace and on a recorded one,
-the metric readers that take from it, and a CPU rehearsal of
-``profile_spans.py``."""
+and the metric readers that take from it."""
 import json
 
 import jax
 import pytest
 
-import profile_spans
 import run
 import span_reduce as sr
 from repro.cimsim.functional import make_input
@@ -213,46 +211,10 @@ def test_readers_none_where_a_piece_is_missing():
     assert _read("batcher_wait_ms.server", empty) is None
 
 
-def test_new_readers_are_listed_or_wait_for_the_traced_path():
-    """``calibrate_s`` is in ``BENCHMARK.json``; the rest wait for
-    ``run.py``'s traced path to switch the program's sinks on, and
-    ``profile_spans.py`` reads them meanwhile."""
+def test_every_reader_is_listed():
+    """Every reader of ``bench/metrics/`` is a metric of
+    ``BENCHMARK.json``: the traced path reads them all."""
     spec = json.loads((run.ROOT / "BENCHMARK.json").read_text())
-    listed = {m["name"] for m in spec["per_layer"]}
-    assert "calibrate_s" in listed
-    names = profile_spans.readers([])
-    assert "host_prep_ms.offline" in names and "calibrate_s" not in names
-
-
-# -- profile_spans.py, rehearsed on the CPU at a tiny size -------------------------
-
-BACKLOG = dict(run.traffic.load(run.BENCH / "traffic" / "offline.json"),
-               pool=16)
-POISSON = dict(run.traffic.load(run.BENCH / "traffic" / "poisson-38.json"),
-               rate_per_s=150.0, pool=16)
-
-
-@pytest.mark.parametrize("mix", [BACKLOG, POISSON], ids=["backlog", "poisson"])
-def test_profile_spans_rehearsal(mix):
-    import dataclasses
-    from repro.kernels.cim_mvm import cim_mvm_params
-    arch = "isaac-baseline"
-    cfg = {"network": "tiny_cnn", "sizes": {}, "arch": arch,
-           "cim": dataclasses.asdict(cim_mvm_params(get_arch(arch))),
-           "buckets": [1, 2, 4, 8], "max_wait_s": 0.002,
-           "check_requests": 4}
-    names = profile_spans.readers([])
-    off, on = profile_spans.profile(cfg, mix, names, seed=2 ** 31 + 9,
-                                    seconds=0.5, sinks=[0, 1],
-                                    require_tpu=False)
-    assert off["correct"] and on["correct"]
-    assert off["sinks"] == 0 and on["sinks"] == 1
-    host = {"host_prep_ms.offline", "device_wait_ms.offline",
-            "fetch_ms.offline"}
-    counters = {"batcher_wait_ms.server", "bucket_fill.server"}
-    assert not (host | counters) & set(off["metrics"])
-    assert host | counters <= set(on["metrics"])
-    assert 0 < on["metrics"]["bucket_fill.server"] <= 100
-    # the CPU has no device plane: nothing of the device is read
-    assert not {"im2col_ms.offline", "gemm_ms.offline",
-                "idle_in_program_share.offline"} & set(on["metrics"])
+    listed = {m["name"] for m in spec["end_to_end"] + spec["per_layer"]}
+    files = {p.stem for p in (run.BENCH / "metrics").glob("*.py")}
+    assert files == listed

@@ -24,7 +24,7 @@ def test_unknown_device_has_no_peaks():
 
 
 @pytest.mark.parametrize("name,sizes", [("resnet18", {"in_hw": 224}),
-                                        ("tiny_cnn", {})])
+                                        ("tiny_cnn", {}), ("tiny_mlp", {})])
 def test_reference_net_mirrors_served_graph(name, sizes):
     net, graph = _net(name, **sizes), get_workload(name, **sizes)
     ours = [(n["name"], n["R"], n["C"]) for n in reference.cim_nodes(net)]

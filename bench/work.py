@@ -9,7 +9,7 @@ from __future__ import annotations
 import json
 from pathlib import Path
 
-from reference import cim_nodes
+from reference import is_crossbar
 
 PEAKS = Path(__file__).resolve().parent / "peaks.json"
 
@@ -23,7 +23,17 @@ def peaks(device_kind: str) -> dict:
     return table[device_kind]
 
 
+def node_macs(node: dict) -> int:
+    """A node's multiply-accumulates per inference: its own ``macs``
+    where it states them (a matmul the ALU runs), windows x R x C for a
+    crossbar node, none otherwise."""
+    if "macs" in node:
+        return node["macs"]
+    if is_crossbar(node):
+        return node["windows"] * node["R"] * node["C"]
+    return 0
+
+
 def macs_per_inference(net: dict) -> int:
-    """Multiply-accumulates of one inference: windows x R x C summed over
-    the crossbar nodes (convolutions and the fully connected layer)."""
-    return sum(n["windows"] * n["R"] * n["C"] for n in cim_nodes(net))
+    """Multiply-accumulates of one inference, summed over the nodes."""
+    return sum(node_macs(n) for n in net["nodes"])
