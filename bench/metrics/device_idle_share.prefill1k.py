@@ -1,0 +1,10 @@
+"""Share of the traced window in which no operation ran on the chip:
+1 - (union of the device operations' intervals) / (window), from the
+profiler trace."""
+
+
+def read(rec):
+    t = rec["trace"]
+    if not t or t["window_s"] <= 0:
+        return None
+    return 100.0 * (1.0 - t["busy_s"] / t["window_s"])

@@ -16,9 +16,16 @@ jitted executable with the same bit-exact semantics:
     configs), or the whole node folds into a single int32 matmul (the
     provably-exact ADC case);
   * ``shift_acc``, requantization and the DCOM operators are traced
-    into the same jnp graph (rare float-reference ops run through
-    ``jax.pure_callback`` so they stay bit-identical to the NumPy
-    reference);
+    into the same jnp graph, every one of them the integer rule of
+    ``cimsim.functional``'s ALU contract (tables built at lowering), so
+    nothing leaves the device inside a dispatch;
+  * routed experts (``MoECombine`` and the routed Gemms that feed it)
+    multiply only the rows routed to them: per held expert the routed
+    token rows are gathered in rounds of a static ``S`` rows (twice the
+    expected share, see ``_route_rows``), each round runs the expert's
+    whole chain and scatter-adds its gated output; an expert routed more
+    than ``S`` rows runs further rounds, in a loop, so no row is
+    dropped;
   * every tensor carries a leading batch axis, so N inferences execute
     in one dispatch (``run_batch``);
   * **multi-segment schedules stream weight updates through the trace**:
@@ -52,6 +59,7 @@ against it across chip modes, saturating-ADC configs and batch sizes.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from collections import OrderedDict
@@ -63,19 +71,19 @@ from ..obs import metrics as obs_metrics
 from ..obs import trace as obs_trace
 from ..core.abstraction import CIMArch
 from ..core.cg_opt import OpPlacement, SchedulePlan
-from ..core.graph import Graph, Node, weight_matrix_shape
+from ..core.graph import Graph, Node, route_share, weight_matrix_shape
 from ..core.mop import Program
 from ..kernels import backend
 from ..kernels.cim_mvm import CimMvmParams, cim_mvm_params
 from ..kernels.cim_mvm.ops import _cim_mvm_tiles_impl
-from .functional import (_float_dcom, chunk_offsets, spread_slice,
-                         tile_ranges)
+from . import functional as fn
+from .functional import chunk_offsets, spread_slice, tile_ranges
 
 _INT32_MAX = 2 ** 31 - 1
 
-#: largest weight-matrix R for which the exact-ADC path may use the
-#: split-plane f32 GEMM: per-plane |partial| <= R * 128 * 15 must stay
-#: under 2^24 (the f32 exact-integer range), so R <= 8192 is safe.
+#: rows of one split-plane f32 GEMM of the exact-ADC path: per-plane
+#: |partial| <= R * 128 * 15 must stay under 2^24 (the f32 exact-integer
+#: range), so R <= 8192 is safe; taller matrices sum such chunks in int32
 _F32_SPLIT_MAX_R = 8192
 
 #: DCOM graph ops the lowering can trace (parity with apply_dcom).
@@ -83,11 +91,70 @@ _SUPPORTED_DCOM = {
     "Relu", "Add", "Mul", "MaxPool", "AveragePool", "GlobalAveragePool",
     "Flatten", "Reshape", "Identity", "Transpose", "Concat", "Split",
     "MatMul", "Gelu", "Silu", "Sigmoid", "Tanh", "Softmax", "LayerNorm",
-    "RMSNorm",
+    "RMSNorm", "RoPE", "TopKRouter", "MoECombine",
 }
 
 #: ops whose lowering consumes a calibrated requantization shift
-_SHIFTED_DCOM = {"Add", "Mul", "MatMul"}
+_SHIFTED_DCOM = {"Add", "Mul", "MatMul", "MoECombine"}
+
+#: ops a routed expert's chain may hold between its routed Gemms: row-wise,
+#: so a round of gathered rows computes them as the whole tensor would
+_ROWWISE = {"Relu", "Gelu", "Silu", "Sigmoid", "Tanh", "Mul", "Add"}
+
+#: contraction block of an ALU MatMul: int8-range planes summed over 1024
+#: products stay within 2**24, the f32 exact-integer range
+_MATMUL_BLOCK = 1024
+
+#: rows of one routing round per held expert, as a multiple of the
+#: expected routed share of the dispatch's token rows
+_ROUTE_SLACK = 2
+
+#: key of the routed-rows counter among a routed program's device outputs
+ROUTED_ROWS = "__routed_rows__"
+
+
+def _scopes(node: Node):
+    """The ``jax.named_scope``s of a node's ops: the parts of its
+    ``scope`` attr (the block it belongs to, e.g. ``mla/attn``), then its
+    name."""
+    import jax
+    stack = contextlib.ExitStack()
+    for part in filter(None, node.attrs.get("scope", "").split("/")):
+        stack.enter_context(jax.named_scope(part))
+    stack.enter_context(jax.named_scope(node.name))
+    return stack
+
+
+def _select(table: np.ndarray, idx):
+    """``table[idx]`` for a small constant table, as a tree of selects on
+    the bits of ``idx`` (an index gather is slow on the device); equal
+    neighbours fold into one constant, and an int8 table selects int8
+    values (a quarter of the bytes wherever XLA keeps a level)."""
+    import jax.numpy as jnp
+    dt = jnp.int8 if table.min() >= -128 and table.max() <= 127 \
+        else jnp.int32
+    vals: List[Any] = [int(v) for v in table]
+    bit = 0
+    while len(vals) > 1:
+        on = ((idx >> bit) & 1) == 1
+        vals = [a if isinstance(a, int) and isinstance(b, int) and a == b
+                else jnp.where(on, jnp.asarray(b, dt), jnp.asarray(a, dt))
+                for a, b in zip(vals[0::2], vals[1::2])]
+        bit += 1
+    return jnp.full(idx.shape, vals[0], jnp.int32) \
+        if isinstance(vals[0], int) else vals[0].astype(jnp.int32)
+
+
+def _isqrt(n):
+    """floor(sqrt(n)) of int32 ``n`` in [0, 2**30], exactly and without a
+    float root (the device's may be approximate): set the root's 16 bits
+    from the top, keeping a bit where ``c <= n // c``, i.e. ``c*c <= n``."""
+    import jax.numpy as jnp
+    r = jnp.zeros_like(n)
+    for bit in reversed(range(16)):
+        c = r | (1 << bit)
+        r = jnp.where(c <= n // c, c, r)
+    return r
 
 
 class LoweringError(ValueError):
@@ -352,6 +419,7 @@ class LoweredExecutable:
             self._pool_shapes[key] = (depth, rl, cl)
         self.stats.swaps = len(self._seg_layout)
         self._build_fault_offsets()
+        self._lower_alu()
         self._pool_idx: Dict[str, np.ndarray] = {}
         for node in self.graph.nodes:
             if node.op_type in ("MaxPool", "AveragePool"):
@@ -572,12 +640,12 @@ class LoweredExecutable:
                             w[s[0]:s[1], s[2]:s[3]] = \
                                 self.faults.apply_tile(
                                     name, s, w[s[0]:s[1], s[2]:s[3]])
-                if cp.r <= _F32_SPLIT_MAX_R and self.params.act_bits <= 8 \
-                        and self.params.weight_bits <= 8:
+                if self.params.act_bits <= 8 and self.params.weight_bits <= 8:
                     # split-plane GEMM: w = 16*w_hi + w_lo with w_hi in
                     # [-8,7], w_lo in [0,15]; planes and int8 activations
                     # are exact in bf16, and each f32 partial product sum
-                    # stays under 2^24, so the fast float GEMM is exact
+                    # over at most _F32_SPLIT_MAX_R rows stays under 2^24,
+                    # so the fast float GEMM is exact
                     packed[name] = {"hi": jnp.asarray((w >> 4), jnp.bfloat16),
                                     "lo": jnp.asarray((w & 15), jnp.bfloat16)}
                 else:
@@ -687,7 +755,21 @@ class LoweredExecutable:
             if sp:
                 jax.block_until_ready(out)
         with span("cim.executor.fetch", track, wl):
-            return {name: np.asarray(v) for name, v in out.items()}
+            out = {name: np.asarray(v) for name, v in out.items()}
+        rows = out.pop(ROUTED_ROWS, None)
+        if rows is not None:
+            self._count_routed(rows)
+        return out
+
+    def _count_routed(self, rows: np.ndarray) -> None:
+        """The program counter ``executor_routed_rows_total{node,expert}``:
+        token rows routed to each held expert, summed over dispatches."""
+        if obs_metrics.active() is None:
+            return
+        for name, per_expert in zip(self._combines, rows):
+            for j, n in enumerate(per_expert):
+                obs_metrics.count("executor_routed_rows_total", float(n),
+                                  node=name, expert=j)
 
     # -- the traced program ----------------------------------------------
     def _swap_chain(self, segs):
@@ -718,9 +800,18 @@ class LoweredExecutable:
             with jax.named_scope("swap"):
                 pools = self._swap_chain(packed["segs"])
         tensors: Dict[str, Any] = dict(inputs)
+        routed: List[Any] = []
         for node in self.graph.nodes:
-            xs = [tensors[t] for t in node.inputs]
-            with jax.named_scope(node.name):
+            if node.name in self._in_region:
+                continue             # runs inside its MoECombine's rounds
+            with _scopes(node):
+                if node.op_type == "MoECombine":
+                    y, rows = self._combine(node, tensors, packed, shifts,
+                                            pools)
+                    tensors[node.outputs[0]] = y
+                    routed.append(rows)
+                    continue
+                xs = [tensors[t] for t in node.inputs]
                 if node.is_cim:
                     pw = None if self._stream else packed[node.name]
                     tensors[node.outputs[0]] = self._cim(
@@ -731,7 +822,11 @@ class LoweredExecutable:
                         tensors[name] = part
                 else:
                     tensors[node.outputs[0]] = self._dcom(node, xs, shifts)
-        return {t: tensors[t] for t in self.graph.outputs}
+        out = {t: tensors[t] for t in self.graph.outputs}
+        if routed:
+            import jax.numpy as jnp
+            out[ROUTED_ROWS] = jnp.stack(routed)
+        return out
 
     def _rows(self, node: Node, x):
         """(N, windows, R) MVM input rows (im2col for Conv)."""
@@ -761,10 +856,17 @@ class LoweredExecutable:
         n, m, _ = rows.shape
         if cp.exact:
             if "hi" in pw:
-                hi, lo = (jnp.matmul(rows, pw[p],
-                                     preferred_element_type=jnp.float32)
-                          for p in ("hi", "lo"))
-                acc = (hi.astype(jnp.int32) << 4) + lo.astype(jnp.int32)
+                parts = []
+                for r0 in range(0, cp.r, _F32_SPLIT_MAX_R):
+                    r1 = min(cp.r, r0 + _F32_SPLIT_MAX_R)
+                    one = cp.r <= _F32_SPLIT_MAX_R
+                    xr = rows if one else rows[..., r0:r1]
+                    hi, lo = (jnp.matmul(xr, pw[p] if one else pw[p][r0:r1],
+                                         preferred_element_type=jnp.float32)
+                              for p in ("hi", "lo"))
+                    parts.append((hi.astype(jnp.int32) << 4)
+                                 + lo.astype(jnp.int32))
+                acc = sum(parts[1:], parts[0])
             else:
                 acc = jnp.matmul(rows, pw["w"],
                                  preferred_element_type=jnp.int32)
@@ -889,22 +991,247 @@ class LoweredExecutable:
             axis = node.attrs.get("axis", -1)
             return jnp.concatenate(xs, axis if axis < 0 else axis + 1)
         if t == "MatMul":
-            b = xs[1]
-            if node.attrs.get("transpose_b"):
-                b = jnp.swapaxes(b, -1, -2)
-            y = jnp.matmul(xs[0], b, preferred_element_type=jnp.int32)
-            return jnp.clip(y >> shifts[node.name], -128, 127) \
-                .astype(jnp.int32)
-        # float-reference ops: the NumPy float64 path is the contract, so
-        # call it (batch-transparent: elementwise / last-axis only)
-        x = xs[0]
+            return self._matmul(node, xs, shifts[node.name])
+        with jax.named_scope("alu"):
+            return self._alu(node, xs[0])
 
-        def cb(xv):
-            y = _float_dcom(t, [np.asarray(xv)], node)
-            return np.clip(np.round(y * 32.0), -128, 127).astype(np.int32)
+    def _matmul(self, node: Node, xs: List, sh):
+        """An activation x activation product, exact: bf16 operand planes
+        of int8 range, f32 sums over contraction blocks of
+        ``_MATMUL_BLOCK`` (each under 2**24), int32 across blocks.  A
+        left operand of Softmax probabilities (up to 2**15) rides two
+        planes, its low 7 bits and the rest; the rest sums to at most
+        (2**15 + K) / 128 over a row, far inside the range."""
+        import jax.numpy as jnp
+        a, b = xs
+        if node.attrs.get("transpose_b"):
+            b = jnp.swapaxes(b, -1, -2)
+        planes = [(a & 127, 0), (a >> 7, 7)] if node.name in self._mm_wide \
+            else [(a, 0)]
+        k = a.shape[-1]
+        bf = b.astype(jnp.bfloat16)
+        acc = None
+        for k0 in range(0, k, _MATMUL_BLOCK):
+            k1 = min(k, k0 + _MATMUL_BLOCK)
+            for p, lsh in planes:
+                part = jnp.matmul(p[..., k0:k1].astype(jnp.bfloat16),
+                                  bf[..., k0:k1, :],
+                                  preferred_element_type=jnp.float32)
+                part = part.astype(jnp.int32) << lsh
+                acc = part if acc is None else acc + part
+        return jnp.clip(acc >> sh, -128, 127).astype(jnp.int32)
 
-        return jax.pure_callback(
-            cb, jax.ShapeDtypeStruct(x.shape, jnp.int32), x)
+    def _alu(self, node: Node, x):
+        """The integer ALU rules of ``cimsim.functional`` on the device."""
+        import jax.numpy as jnp
+        from jax import lax
+        t = node.op_type
+        table = self._tables.get(node.name)
+        _, fout = fn.alu_fracs(node)
+        if t in fn.ELEMENTWISE:
+            return _select(table, x + 128)
+        if t in ("Softmax", "TopKRouter"):
+            causal = bool(node.attrs.get("causal"))
+            keep = None
+            if causal:
+                rows, cols = x.shape[-2:]
+                keep = lax.broadcasted_iota(jnp.int32, (rows, cols), 1) <= \
+                    lax.broadcasted_iota(jnp.int32, (rows, cols), 0)
+            m = (jnp.where(keep, x, -(1 << 20)) if causal else x) \
+                .max(axis=-1, keepdims=True)
+            d = jnp.clip(m - x, 0, 255)
+            e = (_select(table[0], d >> 4) * _select(table[1], d & 15)
+                 + (1 << (fn.EXP_FRAC - 1))) >> fn.EXP_FRAC
+            if causal:
+                e = jnp.where(keep, e, 0)
+            if t == "Softmax":
+                s = e.sum(axis=-1, keepdims=True)
+                return ((e << fout) + (s >> 1)) // s
+            s = e.sum(axis=-1, keepdims=True)
+            p = ((e << fn.GATE_FRAC) + (s >> 1)) // s
+            n_e, k = x.shape[-1], node.attrs["k"]
+            key = x * n_e + (n_e - 1 - jnp.arange(n_e, dtype=jnp.int32))
+            kth = lax.top_k(key, k)[0][..., k - 1:k]
+            g = jnp.where(key >= kth, jnp.maximum(p, 1), 0)
+            held = node.attrs.get("held")
+            return g if held is None else g[..., np.asarray(held)]
+        if t in ("RMSNorm", "LayerNorm"):
+            d = x.shape[-1]
+            if t == "LayerNorm":
+                x = x - (2 * x.sum(axis=-1, keepdims=True) + d) // (2 * d)
+            ss = (x * x).sum(axis=-1, keepdims=True)
+            m = 16 * (ss // d) + (32 * (ss % d) + d) // (2 * d)
+            s = _isqrt(m << 10)
+            y = ((x << (fout + 8)) + s) // jnp.maximum(2 * s, 1)
+            return jnp.clip(jnp.where(s > 0, y, 0), -128, 127)
+        if t == "RoPE":
+            cos, sin = table
+            half = x.shape[-1] // 2
+            shape = (1, x.shape[1]) + (1,) * (x.ndim - 3) + (half,)
+            c, s = jnp.asarray(cos).reshape(shape), \
+                jnp.asarray(sin).reshape(shape)
+            x1, x2 = x[..., :half], x[..., half:]
+            rnd = 1 << (fn.ROPE_FRAC - 1)
+            y = jnp.concatenate([(x1 * c - x2 * s + rnd) >> fn.ROPE_FRAC,
+                                 (x2 * c + x1 * s + rnd) >> fn.ROPE_FRAC],
+                                axis=-1)
+            return jnp.clip(y, -128, 127)
+        raise LoweringError(f"no device rule for {t}")
+
+    # -- routed experts ---------------------------------------------------
+    def _lower_alu(self) -> None:
+        """Tables of the ALU rules, the MatMuls whose left operand needs
+        two planes, and each MoECombine's routed regions."""
+        g = self.graph
+        self._tables: Dict[str, Any] = {}
+        self._mm_wide: set = set()
+        for node in g.nodes:
+            t = node.op_type
+            if t in fn.ELEMENTWISE:
+                self._tables[node.name] = fn.elementwise_table(node)
+            elif t in ("Softmax", "TopKRouter"):
+                self._tables[node.name] = fn.exp_factors(
+                    float(node.attrs.get("scale", 1.0)),
+                    fn.alu_fracs(node)[0])
+            elif t == "RoPE":
+                self._tables[node.name] = fn.rope_tables(
+                    node, g.shapes[node.inputs[0]][0])
+            elif t == "MatMul":
+                a, b = (g.producer(x) for x in node.inputs)
+                if b is not None and _wide(b):
+                    raise LoweringError(f"{node.name}: right operand wider "
+                                        "than int8")
+                if a is not None and _wide(a):
+                    self._mm_wide.add(node.name)
+                if g.shapes[node.inputs[0]][-1] > (1 << 17):
+                    raise LoweringError(f"{node.name}: accumulation exceeds "
+                                        "int32")
+        self._combines = [n.name for n in g.nodes if n.op_type == "MoECombine"]
+        self._regions: Dict[str, List[List[Node]]] = {}
+        self._in_region: set = set()
+        for name in self._combines:
+            node = g.node(name)
+            regions = [self._region(node, j)
+                       for j in range(len(node.inputs) - 1)]
+            self._regions[name] = regions
+            for r in regions:
+                self._in_region.update(n.name for n in r)
+
+    def _region(self, combine: Node, j: int) -> List[Node]:
+        """The nodes of held expert j's chain: those between a routed Gemm
+        of route j and the combine's input j + 1, in graph order."""
+        g = self.graph
+        anc, stack = set(), [g.producer(combine.inputs[j + 1])]
+        while stack:
+            n = stack.pop()
+            if n is None or n.name in anc:
+                continue
+            anc.add(n.name)
+            stack.extend(g.producer(t) for t in n.inputs)
+        region = []
+        names: set = set()
+        for n in g.nodes:
+            if n.name not in anc:
+                continue
+            routed = n.is_cim and n.attrs.get("route") == j \
+                and n.inputs[1:] == combine.inputs[:1]
+            fed = any((p := g.producer(t)) is not None and p.name in names
+                      for t in (n.inputs[:1] if n.is_cim else n.inputs))
+            if routed or fed:
+                if not (routed or n.op_type in _ROWWISE):
+                    raise LoweringError(f"{n.name}: {n.op_type} inside a "
+                                        "routed expert is not row-wise")
+                region.append(n)
+                names.add(n.name)
+        out = g.producer(combine.inputs[j + 1])
+        if out is None or out.name not in names:
+            raise LoweringError(f"{combine.name}: input {j + 1} is not a "
+                                "routed expert's output")
+        for n in region:
+            for c in g.consumers(n.outputs[0]):
+                if c.name not in names and c is not combine:
+                    raise LoweringError(f"{n.name} feeds {c.name} outside "
+                                        "its routed expert")
+        return region
+
+    @staticmethod
+    def _route_rows(rows: int, share: float) -> int:
+        """``S``: the rows of one routing round of a held expert,
+        ``_ROUTE_SLACK`` times its expected share of the dispatch's
+        ``rows``, rounded up to 128 (8 below that), at most ``rows``."""
+        s = int(np.ceil(_ROUTE_SLACK * np.ceil(rows * share)))
+        q = 128 if s >= 128 else 8
+        return max(1, min(rows, -(-s // q) * q))
+
+    def _combine(self, node: Node, tensors, packed, shifts, pools):
+        """``sum_j gate_j * expert_j(x)`` over the held experts, each
+        computed on its routed rows only; returns the requantized output
+        and the rows routed to each held expert (the program counter)."""
+        import jax
+        import jax.numpy as jnp
+        from jax import lax
+        gates = tensors[node.inputs[0]]
+        lead = gates.shape[:-1]
+        rows = int(np.prod(lead))
+        g = gates.reshape(rows, gates.shape[-1])
+        d_out = self.graph.shapes[node.outputs[0]][-1]
+        acc = jnp.zeros((rows, d_out), jnp.int32)
+        counts = []
+        for j, region in enumerate(self._regions[node.name]):
+            s = self._route_rows(rows, route_share(region[0]))
+            with jax.named_scope("moe_dispatch"):
+                sel = g[:, j] > 0
+                count = sel.sum(dtype=jnp.int32)
+                idx = jnp.nonzero(sel, size=-(-rows // s) * s,
+                                  fill_value=0)[0].astype(jnp.int32)
+
+            def body(r, acc, region=region, j=j, idx=idx, count=count, s=s):
+                with jax.named_scope("moe_dispatch"):
+                    tok = lax.dynamic_slice(idx, (r * s,), (s,))
+                    live = r * s + jnp.arange(s, dtype=jnp.int32) < count
+                with jax.named_scope("experts"):
+                    y = self._run_region(region, node.inputs[j + 1], tok,
+                                         tensors, packed, shifts, pools)
+                with jax.named_scope("combine"):
+                    gj = jnp.where(live, g[tok, j], 0)
+                    return acc.at[tok].add(gj[:, None] * y)
+
+            acc = lax.fori_loop(0, (count + s - 1) // s, body, acc)
+            counts.append(count)
+        with jax.named_scope("combine"):
+            y = jnp.clip(acc >> shifts[node.name], -128, 127).astype(jnp.int32)
+        return y.reshape(lead + (d_out,)), jnp.stack(counts)
+
+    def _run_region(self, region: List[Node], out: str, tok, tensors,
+                    packed, shifts, pools):
+        """One round of a held expert's chain on the token rows ``tok``:
+        (S, width) rows gathered from the tensors outside the chain."""
+        vals: Dict[str, Any] = {}
+
+        def get(t):
+            if t not in vals:
+                x = tensors[t]
+                vals[t] = x.reshape(-1, x.shape[-1])[tok]
+            return vals[t]
+
+        for rn in region:
+            with _scopes(rn):
+                if rn.is_cim:
+                    pw = None if self._stream else packed[rn.name]
+                    y = self._cim(rn, get(rn.inputs[0])[None], pw,
+                                  shifts[rn.name], pools)[0]
+                else:
+                    y = self._dcom(rn, [get(t) for t in rn.inputs], shifts)
+            vals[rn.outputs[0]] = y
+        return vals[out]
+
+
+def _wide(producer: Node) -> bool:
+    """Whether a node's output leaves the int8 range: Softmax
+    probabilities above 7 fractional bits, or a router's gates."""
+    if producer.op_type == "TopKRouter":
+        return True
+    return producer.op_type == "Softmax" and fn.alu_fracs(producer)[1] > 7
 
 
 # ---------------------------------------------------------------------------

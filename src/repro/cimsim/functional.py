@@ -20,6 +20,56 @@ The simulator walks the *expanded* Program op by op:
 Equality with the reference is bit-exact whenever the ADC does not
 saturate (``CimMvmParams.exact``); with a narrow ADC the simulator
 reports the (hardware-true) saturated results.
+
+The ALU contract.  Every digital operator is integer arithmetic on int8
+activations, so the executor computes it on the device bit for bit (no
+host callback).  A node's ``frac`` attr gives the fractional bits of its
+int8 input (real value = v / 2**frac) and ``out_frac`` those of its
+output; the defaults (0 in, 5 out) are the historical contract.
+
+* Elementwise Gelu / Silu / Sigmoid / Tanh: a 256-entry table built at
+  lowering, ``T[v] = clip(round(2**out_frac * f(v / 2**frac)), -128,
+  127)`` in float64, so both simulators index the same integers.  Error
+  against ``f``: at most half a step of ``2**-out_frac`` (before clip).
+* Softmax over the last axis (``causal``: key j > query i is left out;
+  ``scale`` multiplies the real logit): ``d = max - v`` in [0, 255],
+  ``e = E[d] = (A[d >> 4] B[d & 15] + 2**14) >> 15`` with the 16-entry
+  ``A[i] = round(2**15 exp(-16 i c))``, ``B[j] = round(2**15 exp(-j c))``,
+  ``c = scale / 2**frac`` (two small tables, so the device selects
+  rather than gathers), ``S = sum e``, ``p = (e * 2**out_frac + S // 2)
+  // S``.  int32 bound: ``e * 2**out_frac <= 2**30`` for ``out_frac <=
+  15``; rows up to 2**16.  Error against the float64 softmax: half a
+  step of ``2**-out_frac`` plus ``e``'s 1.5 steps of 2**-15 relative,
+  under 2**-14 per entry at ``out_frac`` 15.
+* RMSNorm / LayerNorm over the last axis of length D (LayerNorm first
+  subtracts ``mu = (2 sum x + D) // (2 D)``, the rounded mean; weights
+  are ones): ``ss = sum x**2``; the mean square in sixteenths,
+  ``m = 16 (ss // D) + (32 (ss % D) + D) // (2 D)``, is
+  ``round(16 ss / D)``; ``s = isqrt(m * 2**10)`` = floor(128 rms);
+  ``y = clip((x * 2**(out_frac + 8) + s) // (2 s), -128, 127)`` (0 where
+  s is 0).  Every step fits int32 for D <= 2**15.  ``eps`` (1e-6 of the
+  real mean square) lies below the rule's resolution and is dropped.
+  Error against ``x / rms``: the rms is read to 1/(128 rms) relative.
+* RoPE on the last axis, rotate-half layout, at the position along the
+  first (token) axis: ``c = round(2**14 cos(t theta_i))``, ``s`` likewise
+  (tables built at lowering from float64, YaRN frequencies where
+  ``scaling`` says so); ``y1 = (x1 c - x2 s + 2**13) >> 14``,
+  ``y2 = (x2 c + x1 s + 2**13) >> 14``, clipped to int8.  Error: half
+  a step plus ``|x| 2**-14``.
+* TopKRouter over ``n_experts`` int8 logits (``frac`` as above): the
+  ``k`` largest logits are chosen, ties to the lower expert index;
+  gates are the Softmax rule above at ``out_frac`` 15 over all experts;
+  a chosen expert's gate is at least 1 (so chosen means gate > 0),
+  every other gate is 0.  Output: the gates of the ``held`` experts.
+* MatMul (activation x activation, batched over leading head axes):
+  the exact integer product, requantized by the node's shift; its left
+  operand may be Softmax probabilities (to 2**15: a row sums to at most
+  ``2**out_frac + K/2``), its right one is int8.
+* MoECombine: ``sum_j gate_j * y_j`` over the held experts (int32: at
+  most k * 2**15 * 128), requantized by the node's calibrated shift.
+* A routed Gemm (attr ``route`` = j, second input the gates) multiplies
+  only the rows whose gate j is > 0; its other rows are 0, and its
+  shift is calibrated on the routed rows alone.
 """
 from __future__ import annotations
 
@@ -97,29 +147,181 @@ def im2col(x: np.ndarray, k: int, stride: int, pad: int) -> np.ndarray:
 
 
 # ---------------------------------------------------------------------------
-# Reference executor (int8 fake-quant, exact integer matmuls)
+# The integer ALU contract (module docstring); the executor builds the same
+# tables and computes the same integer steps on the device
 # ---------------------------------------------------------------------------
 
-def _float_dcom(op_type: str, xs: List[np.ndarray],
-                node: Node) -> np.ndarray:
-    x = xs[0].astype(np.float64)
-    if op_type == "Gelu":
-        return x * 0.5 * (1.0 + np.tanh(0.7978845608 * (x + 0.044715 * x ** 3)))
-    if op_type == "Silu":
-        return x / (1.0 + np.exp(-x))
-    if op_type == "Sigmoid":
-        return 1.0 / (1.0 + np.exp(-x))
-    if op_type == "Tanh":
-        return np.tanh(x)
-    if op_type == "Softmax":
-        e = np.exp(x - x.max(axis=-1, keepdims=True))
-        return e / e.sum(axis=-1, keepdims=True)
-    if op_type in ("LayerNorm", "RMSNorm"):
-        if op_type == "LayerNorm":
-            x = x - x.mean(axis=-1, keepdims=True)
-        return x / np.sqrt((x ** 2).mean(axis=-1, keepdims=True) + 1e-6)
-    raise ValueError(f"no float DCOM for {op_type}")
+#: elementwise float functions tabulated over the 256 int8 inputs
+ELEMENTWISE = {
+    "Gelu": lambda x: x * 0.5 * (1.0 + np.tanh(
+        0.7978845608 * (x + 0.044715 * x ** 3))),
+    "Silu": lambda x: x / (1.0 + np.exp(-x)),
+    "Sigmoid": lambda x: 1.0 / (1.0 + np.exp(-x)),
+    "Tanh": np.tanh,
+}
+#: fractional bits of a gate (TopKRouter output), of the RoPE tables and
+#: of the Softmax rule's exponentials
+GATE_FRAC = 15
+ROPE_FRAC = 14
+EXP_FRAC = 15
 
+
+def alu_fracs(node: Node) -> Tuple[int, int]:
+    """(input, output) fractional bits of an ALU node's int8 values."""
+    return int(node.attrs.get("frac", 0)), int(node.attrs.get("out_frac", 5))
+
+
+def elementwise_table(node: Node) -> np.ndarray:
+    """(256,) int32 outputs of an elementwise op, indexed by v + 128."""
+    fin, fout = alu_fracs(node)
+    v = np.arange(-128, 128, dtype=np.float64) / (1 << fin)
+    y = ELEMENTWISE[node.op_type](v) * (1 << fout)
+    return np.clip(np.round(y), -128, 127).astype(np.int32)
+
+
+def exp_factors(scale: float, frac: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(16,) int32 ``A[i] = round(2**15 exp(-16 i c))`` and
+    ``B[j] = round(2**15 exp(-j c))``, ``c = scale / 2**frac``."""
+    c = scale / (1 << frac)
+    i = np.arange(16, dtype=np.float64)
+    one = 1 << EXP_FRAC
+    return (np.round(one * np.exp(-16 * i * c)).astype(np.int32),
+            np.round(one * np.exp(-i * c)).astype(np.int32))
+
+
+def exp_table(scale: float, frac: int) -> np.ndarray:
+    """(256,) int32 ``E[d] = (A[d >> 4] B[d & 15] + 2**14) >> 15``, the
+    Softmax rule's ``exp(-d c)`` in Q15 (``exp_factors``)."""
+    a, b = (f.astype(np.int64) for f in exp_factors(scale, frac))
+    prod = a[:, None] * b[None, :] + (1 << (EXP_FRAC - 1))
+    return (prod >> EXP_FRAC).reshape(256).astype(np.int32)
+
+
+def softmax_exp_table(node: Node) -> np.ndarray:
+    """A Softmax or TopKRouter node's ``E`` (the router's scale is 1)."""
+    return exp_table(float(node.attrs.get("scale", 1.0)), alu_fracs(node)[0])
+
+
+def causal_mask(rows: int, cols: int) -> np.ndarray:
+    """(rows, cols) True where key j <= query i."""
+    return np.arange(cols)[None, :] <= np.arange(rows)[:, None]
+
+
+def softmax_int(v: np.ndarray, table: np.ndarray, out_frac: int,
+                causal: bool = False) -> np.ndarray:
+    """The Softmax rule over the last axis of int8 ``v``."""
+    v = v.astype(np.int64)
+    keep = causal_mask(*v.shape[-2:]) if causal else np.ones(v.shape[-1:],
+                                                             bool)
+    m = np.where(keep, v, -(1 << 20)).max(axis=-1, keepdims=True)
+    e = np.where(keep, table[np.clip(m - v, 0, 255)], 0).astype(np.int64)
+    s = e.sum(axis=-1, keepdims=True)
+    return ((e << out_frac) + s // 2) // s
+
+
+def isqrt(n: np.ndarray) -> np.ndarray:
+    """floor(sqrt(n)) of non-negative integers below 2**52, exactly."""
+    n = np.asarray(n, np.int64)
+    r = np.floor(np.sqrt(n.astype(np.float64))).astype(np.int64)
+    r = np.where(r * r > n, r - 1, r)
+    return np.where((r + 1) * (r + 1) <= n, r + 1, r)
+
+
+def norm_int(x: np.ndarray, out_frac: int, center: bool) -> np.ndarray:
+    """The RMSNorm (``center``: LayerNorm) rule over the last axis."""
+    x = x.astype(np.int64)
+    d = x.shape[-1]
+    if center:
+        x = x - (2 * x.sum(axis=-1, keepdims=True) + d) // (2 * d)
+    ss = (x * x).sum(axis=-1, keepdims=True)
+    m = 16 * (ss // d) + (32 * (ss % d) + d) // (2 * d)
+    s = isqrt(m << 10)
+    y = ((x << (out_frac + 8)) + s) // np.maximum(2 * s, 1)
+    return np.clip(np.where(s > 0, y, 0), -128, 127)
+
+
+def yarn_inv_freq(dim: int, theta: float, scaling: Optional[dict]
+                  ) -> np.ndarray:
+    """Rotary inverse frequencies, blended as YaRN does where ``scaling``
+    (a ``rope_scaling`` dict of type yarn) is given (DeepSeek-V2)."""
+    i = np.arange(0, dim, 2, dtype=np.float64) / dim
+    extra = 1.0 / theta ** i
+    if not scaling:
+        return extra
+    factor = scaling["factor"]
+    orig = scaling["original_max_position_embeddings"]
+
+    def corr(rot):
+        return (dim * math.log(orig / (rot * 2 * math.pi))
+                / (2 * math.log(theta)))
+    lo = max(math.floor(corr(scaling["beta_fast"])), 0)
+    hi = min(math.ceil(corr(scaling["beta_slow"])), dim - 1)
+    hi = hi + 0.001 if lo == hi else hi
+    ramp = np.clip((np.arange(dim // 2) - lo) / (hi - lo), 0.0, 1.0)
+    keep = 1.0 - ramp
+    return extra / factor * (1.0 - keep) + extra * keep
+
+
+def yarn_mscale(scale: float, mscale: float) -> float:
+    return 1.0 if scale <= 1 else 0.1 * mscale * math.log(scale) + 1.0
+
+
+def rope_tables(node: Node, positions: int) -> Tuple[np.ndarray, np.ndarray]:
+    """(positions, dim/2) int32 fixed-point cos and sin of a RoPE node."""
+    a = node.attrs
+    scaling = a.get("scaling")
+    inv = yarn_inv_freq(a["dim"], a.get("theta", 10000.0), scaling)
+    mag = 1.0
+    if scaling:
+        mag = (yarn_mscale(scaling["factor"], scaling["mscale"])
+               / yarn_mscale(scaling["factor"], scaling["mscale_all_dim"]))
+    ang = np.arange(positions, dtype=np.float64)[:, None] * inv[None, :]
+    one = 1 << ROPE_FRAC
+    return (np.round(one * mag * np.cos(ang)).astype(np.int32),
+            np.round(one * mag * np.sin(ang)).astype(np.int32))
+
+
+def rope_int(x: np.ndarray, cos: np.ndarray, sin: np.ndarray) -> np.ndarray:
+    """The RoPE rule: ``x`` is (T, ..., dim), tables (T, dim/2)."""
+    x = x.astype(np.int64)
+    half = x.shape[-1] // 2
+    shape = (x.shape[0],) + (1,) * (x.ndim - 2) + (half,)
+    c, s = cos.reshape(shape), sin.reshape(shape)
+    x1, x2 = x[..., :half], x[..., half:]
+    rnd = 1 << (ROPE_FRAC - 1)
+    y = np.concatenate([(x1 * c - x2 * s + rnd) >> ROPE_FRAC,
+                        (x2 * c + x1 * s + rnd) >> ROPE_FRAC], axis=-1)
+    return np.clip(y, -128, 127)
+
+
+def topk_select(v: np.ndarray, k: int) -> np.ndarray:
+    """Boolean mask of the ``k`` largest entries of each row of int8
+    ``v``, ties to the lower index (a unique key per entry)."""
+    e = v.shape[-1]
+    key = v.astype(np.int64) * e + (e - 1 - np.arange(e))
+    kth = np.sort(key, axis=-1)[..., e - k:e - k + 1]
+    return key >= kth
+
+
+def router_int(v: np.ndarray, node: Node) -> np.ndarray:
+    """The TopKRouter rule: the held experts' gates, (..., held)."""
+    a = node.attrs
+    sel = topk_select(v, a["k"])
+    p = softmax_int(v, softmax_exp_table(node), GATE_FRAC)
+    g = np.where(sel, np.maximum(p, 1), 0)
+    return g[..., list(a.get("held", range(a["n_experts"])))]
+
+
+def routed_rows(node: Node, xs: List[np.ndarray]) -> Optional[np.ndarray]:
+    """Row mask of a routed crossbar node (its gate > 0), else None."""
+    if "route" not in node.attrs:
+        return None
+    return xs[1][..., node.attrs["route"]] > 0
+
+
+# ---------------------------------------------------------------------------
+# Reference executor (int8 fake-quant, exact integer matmuls)
+# ---------------------------------------------------------------------------
 
 def apply_dcom(node: Node, xs: List[np.ndarray], graph: Graph,
                shifts: Dict[str, int],
@@ -160,13 +362,32 @@ def apply_dcom(node: Node, xs: List[np.ndarray], graph: Graph,
         parts = node.attrs["parts"]
         return np.split(xs[0], np.cumsum(parts[:-1]), axis=axis)
     if t == "MatMul":
-        b = xs[1].T if node.attrs.get("transpose_b") else xs[1]
-        y = xs[0].astype(np.int64) @ b.astype(np.int64)
+        b = np.swapaxes(xs[1], -1, -2) if node.attrs.get("transpose_b") \
+            else xs[1]
+        y = np.matmul(xs[0].astype(np.float64), b.astype(np.float64)
+                      ).astype(np.int64)
         sh = _shift_for(node, y, shifts, calibrating)
         return np.clip(y >> sh, -128, 127).astype(np.int32)
-    # float fallback ops re-quantized to int8 grid
-    y = _float_dcom(t, xs, node)
-    return np.clip(np.round(y * 32.0), -128, 127).astype(np.int32)
+    if t in ELEMENTWISE:
+        return elementwise_table(node)[xs[0].astype(np.int64) + 128]
+    if t == "Softmax":
+        return softmax_int(xs[0], softmax_exp_table(node), alu_fracs(node)[1],
+                           bool(node.attrs.get("causal"))).astype(np.int32)
+    if t in ("RMSNorm", "LayerNorm"):
+        return norm_int(xs[0], alu_fracs(node)[1],
+                        t == "LayerNorm").astype(np.int32)
+    if t == "RoPE":
+        return rope_int(xs[0], *rope_tables(node, xs[0].shape[0])
+                        ).astype(np.int32)
+    if t == "TopKRouter":
+        return router_int(xs[0], node).astype(np.int32)
+    if t == "MoECombine":
+        g = xs[0].astype(np.int64)
+        y = sum(g[..., j:j + 1] * x.astype(np.int64)
+                for j, x in enumerate(xs[1:]))
+        sh = _shift_for(node, y, shifts, calibrating)
+        return np.clip(y >> sh, -128, 127).astype(np.int32)
+    raise ValueError(f"no DCOM semantics for {t}")
 
 
 def _shift_for(node: Node, y, shifts: Dict[str, int],
@@ -195,13 +416,20 @@ def _pool(x: np.ndarray, node: Node, reducer) -> np.ndarray:
     return out
 
 
+def exact_mvm(x_rows: np.ndarray, w: np.ndarray) -> np.ndarray:
+    """(M, R) x (R, C) integer product through float64 BLAS: exact, as
+    every product and partial sum of int8 operands is far below 2**53."""
+    return (x_rows.astype(np.float64) @ w.astype(np.float64)).astype(np.int64)
+
+
 def reference_forward(graph: Graph, weights: Dict[str, np.ndarray],
                       inputs: Dict[str, np.ndarray],
                       shifts: Optional[Dict[str, int]] = None,
                       mvm=None) -> Tuple[Dict[str, np.ndarray], Dict[str, int]]:
     """Pure int8 fake-quant forward pass.
 
-    ``mvm(x_rows, w) -> int32`` defaults to the exact integer matmul;
+    ``mvm(x_rows, w) -> int32`` defaults to the exact integer matmul
+    (``exact_mvm``, a float64 BLAS product);
     passing kernels/cim_mvm's signed op makes the reference share the
     crossbar compute semantics (for saturating-ADC comparisons).
     Returns (tensors, calibrated shifts).
@@ -209,14 +437,20 @@ def reference_forward(graph: Graph, weights: Dict[str, np.ndarray],
     calibrating = shifts is None
     shifts = {} if shifts is None else dict(shifts)
     if mvm is None:
-        def mvm(x_rows, w):
-            return x_rows.astype(np.int64) @ w.astype(np.int64)
+        mvm = exact_mvm
     tensors: Dict[str, np.ndarray] = dict(inputs)
     for node in graph.nodes:
         xs = [tensors[t] for t in node.inputs]
         if node.is_cim:
             w = weights[node.name]
-            if node.op_type == "Conv":
+            routed = routed_rows(node, xs)
+            if routed is not None:
+                y = np.asarray(mvm(xs[0][routed], w))
+                sh = _shift_for(node, y, shifts, calibrating)
+                out = np.zeros(xs[0].shape[:-1] + (w.shape[1],), np.int32)
+                out[routed] = np.clip(y >> sh, -128, 127)
+                y = out
+            elif node.op_type == "Conv":
                 k = node.attrs["weight_shape"][2]
                 rows = im2col(xs[0], k, node.attrs.get("stride", 1),
                               node.attrs.get("pad", 0))
@@ -390,6 +624,10 @@ class FunctionalSimulator:
         y = self._acc[node.name]
         sh = self.shifts.get(node.name, 0)
         y = np.clip(y >> sh, -128, 127).astype(np.int32)
+        routed = routed_rows(node, [None] + [self._tensor(t)
+                                             for t in node.inputs[1:]])
+        if routed is not None:
+            y = np.where(routed[:, None], y, 0)
         if node.op_type == "Conv":
             cout = node.attrs["weight_shape"][0]
             oh, ow = self.graph.shapes[node.outputs[0]][1:]

@@ -31,7 +31,7 @@ from typing import Dict, List, Tuple
 
 from ..core.abstraction import CIMArch
 from ..core.cg_opt import OpPlacement, SchedulePlan
-from ..core.graph import Graph, Node
+from ..core.graph import Graph, Node, route_share
 from ..core.mvm_opt import peak_active_xbs
 
 XB_POWER_SHARE = 0.83
@@ -197,7 +197,9 @@ def estimate(plan: SchedulePlan) -> PerfReport:
                 for p in seg.placements:
                     if p.node.name != node.name:
                         continue
-                    cyc = p.stage_cycles
+                    # a routed expert's crossbars fire for its expected
+                    # share of the token rows, not for every row
+                    cyc = p.stage_cycles * route_share(node)
                     compute += cyc
                     n_stages += 1
                     acc.append((start, start + cyc))

@@ -32,3 +32,10 @@ CONFIG = ModelConfig(
     supports_long=False,   # MLA cache is compressed but unbounded in S
     notes="MLA with absorbed decode; 2 shared experts",
 )
+
+#: the published ``config.json`` values the ModelConfig fields do not hold
+ROPE_SCALING = {"type": "yarn", "factor": 40,
+                "original_max_position_embeddings": 4096,
+                "beta_fast": 32, "beta_slow": 1,
+                "mscale": 0.707, "mscale_all_dim": 0.707}
+RMS_NORM_EPS = 1e-6

@@ -8,6 +8,7 @@ exercise (§3.2.1-3.2.3).
 
 All presets from the paper's evaluation are provided:
   * ``isaac_baseline``  — Table 3 (ISAAC-like ReRAM chip, XBM+WLM capable)
+  * ``isaac_node16``    — sixteen of those chips as one 128x128-core tier
   * ``jia_cm``          — Figure 17 (Jia et al. ISSCC'21 SRAM chip, CM)
   * ``puma_xbm``        — Figure 18 (PUMA ReRAM chip, XBM)
   * ``jain_wlm``        — Figure 19 (Jain et al. JSSC'21 SRAM macro, WLM)
@@ -250,6 +251,20 @@ def isaac_baseline(**overrides) -> CIMArch:
     return arch.replace(**overrides) if overrides else arch
 
 
+def isaac_node16(**overrides) -> CIMArch:
+    """A 16-chip ISAAC node modelled as one chip tier (the abstraction has
+    no node tier): ``isaac-baseline``'s core and crossbar tiers with a
+    128x128 core grid, 16x its 32x32.  It holds 536.9M int8 weights
+    (16384 cores x 8 crossbars x 128 rows x 32 weights), the smallest
+    square grid that keeps DeepSeek-V2-Lite's one-rank share (508.8M
+    weights, ``bench/configs/deepseek-v2-lite-isaac16.json``) resident
+    in one segment: ReRAM is not rewritten per batch in a deployment."""
+    arch = isaac_baseline(
+        chip=ChipTier(core_number=(128, 128), alu_ops_per_cycle=1024,
+                      l0_bw_bits=8192))
+    return dataclasses.replace(arch, name="isaac-node16", **overrides)
+
+
 def jia_cm(**overrides) -> CIMArch:
     """Figure 17 — Jia et al. ISSCC'21: 16 CIMUs of 1152x256 SRAM, CM mode.
 
@@ -316,6 +331,7 @@ def toy_example(**overrides) -> CIMArch:
 
 PRESETS = {
     "isaac-baseline": isaac_baseline,
+    "isaac-node16": isaac_node16,
     "jia-issc21": jia_cm,
     "puma": puma_xbm,
     "jain-jssc21": jain_wlm,

@@ -30,7 +30,7 @@ ALU_OPS = {
     "RMSNorm", "BatchNorm", "Add", "Mul", "MaxPool", "AveragePool",
     "GlobalAveragePool", "Flatten", "Reshape", "Concat", "Split",
     "MatMul", "Embedding", "SSMScan", "RoPE", "TopKRouter", "Softcap",
-    "Identity", "Transpose",
+    "Identity", "Transpose", "MoECombine",
 }
 KNOWN_OPS = CIM_OPS | ALU_OPS
 
@@ -221,9 +221,7 @@ def infer_node_shape(n: Node, sh: Dict[str, Tuple[int, ...]]) -> None:
         cin, cout = n.attrs["weight_shape"][-2:]        # (in,out)
         sh[n.outputs[0]] = tuple(x[:-1]) + (cout,)
     elif t == "MatMul":                                 # act x act
-        y = sh[n.inputs[1]]
-        last = y[-2] if n.attrs.get("transpose_b") else y[-1]
-        sh[n.outputs[0]] = tuple(x[:-1]) + (last,)
+        sh[n.outputs[0]] = _matmul_shape(n, x, sh[n.inputs[1]])
     elif t in ("MaxPool", "AveragePool"):
         k = n.attrs.get("kernel", 2)
         stride = n.attrs.get("stride", k)
@@ -256,10 +254,40 @@ def infer_node_shape(n: Node, sh: Dict[str, Tuple[int, ...]]) -> None:
     elif t == "Embedding":
         sh[n.outputs[0]] = tuple(x) + (n.attrs["weight_shape"][1],)
     elif t == "TopKRouter":
-        sh[n.outputs[0]] = tuple(x[:-1]) + (n.attrs["n_experts"],)
+        if x[-1] != n.attrs["n_experts"]:
+            raise ValueError(f"{n}: {x[-1]} router logits for "
+                             f"{n.attrs['n_experts']} experts")
+        held = n.attrs.get("held", range(n.attrs["n_experts"]))
+        sh[n.outputs[0]] = tuple(x[:-1]) + (len(held),)
+    elif t == "MoECombine":                             # (gates, experts...)
+        sh[n.outputs[0]] = sh[n.inputs[1]]
     else:  # elementwise / normalization / misc keep shape of first input
         for o in n.outputs:
             sh[o] = x
+
+
+def _matmul_shape(n: Node, a: Tuple[int, ...], b: Tuple[int, ...]
+                  ) -> Tuple[int, ...]:
+    """Batched activation x activation product: (..., M, K) x (..., K, N)
+    -> (..., M, N), or x (..., N, K) with ``transpose_b``.  Leading (head)
+    axes broadcast as in NumPy; a contraction that does not match is an
+    error here, not when the graph runs."""
+    if len(a) < 2 or len(b) < 2:
+        raise ValueError(f"{n}: MatMul needs operands of rank >= 2, "
+                         f"got {a} and {b}")
+    k_b, cols = (b[-1], b[-2]) if n.attrs.get("transpose_b") \
+        else (b[-2], b[-1])
+    if a[-1] != k_b:
+        raise ValueError(f"{n}: MatMul contracts {a[-1]} against {k_b} "
+                         f"({a} x {b})")
+    rank = max(len(a), len(b))
+    lead_a = (1,) * (rank - len(a)) + tuple(a[:-2])
+    lead_b = (1,) * (rank - len(b)) + tuple(b[:-2])
+    if any(da != db and 1 not in (da, db) for da, db in zip(lead_a, lead_b)):
+        raise ValueError(f"{n}: MatMul batch axes {a[:-2]} and {b[:-2]} "
+                         "do not broadcast")
+    return tuple(max(da, db) for da, db in zip(lead_a, lead_b)) \
+        + (a[-2], cols)
 
 
 # ---------------------------------------------------------------------------
@@ -306,6 +334,14 @@ def macs(n: Node, shapes: Dict[str, Tuple[int, ...]]) -> int:
 
 def out_elems(n: Node, shapes: Dict[str, Tuple[int, ...]]) -> int:
     return int(math.prod(shapes[n.outputs[0]]))
+
+
+def route_share(n: Node) -> float:
+    """Expected share of the token rows a crossbar node multiplies: 1,
+    or for a routed expert its ``route_share`` (experts per token over
+    the router's experts), since it fires only for the tokens routed to
+    it."""
+    return float(n.attrs.get("route_share", 1.0))
 
 
 def weight_bits(n: Node, bits: int) -> int:

@@ -37,8 +37,8 @@ DMOV_KINDS = {"mov"}
 DCOM_KINDS = {
     "relu", "gelu", "silu", "sigmoid", "tanh", "add", "mul", "shift_acc",
     "maxpool", "avgpool", "softmax", "layernorm", "rmsnorm", "matmul",
-    "embedding", "ssm_scan", "rope", "topk_router", "softcap", "identity",
-    "transpose", "concat", "split", "flatten", "reshape",
+    "embedding", "ssm_scan", "rope", "topk_router", "moe_combine", "softcap",
+    "identity", "transpose", "concat", "split", "flatten", "reshape",
 }
 
 

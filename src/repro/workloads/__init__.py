@@ -2,6 +2,7 @@
 from .vgg import vgg7, vgg16
 from .resnet import resnet18, resnet34, resnet50, resnet101
 from .vit import vit_base
+from .deepseek_v2 import deepseek_v2_lite
 from .tiny import tiny_cnn, tiny_mlp, conv_relu_toy
 
 WORKLOADS = {
@@ -12,6 +13,7 @@ WORKLOADS = {
     "resnet50": resnet50,
     "resnet101": resnet101,
     "vit": vit_base,
+    "deepseek_v2_lite": deepseek_v2_lite,
     "tiny_cnn": tiny_cnn,
     "tiny_mlp": tiny_mlp,
     "conv_relu_toy": conv_relu_toy,

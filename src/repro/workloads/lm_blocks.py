@@ -1,89 +1,84 @@
-"""Assigned-LM-architecture blocks as CIM workloads (DESIGN.md §4).
+"""Assigned-LM-architecture blocks as CIM workloads.
 
-Builds the computation graph of one decoder block of any assigned
-architecture so the CIM-MLC compiler can schedule it: weight-stationary
-projections (Q/K/V/O, MLP, expert FFNs, SSM in/out projections) map to
-crossbars; attention QK^T/AV MatMuls, softmax, routing and the SSD scan
-are ALU (DCOM) operators — the weight-stationary applicability split.
+Builds the layers of an assigned architecture (``cfg.pre`` then one
+``cfg.unit`` period) so the CIM-MLC compiler can schedule them:
+weight-stationary projections (Q/K/V/O, MLP, expert FFNs, SSM in/out
+projections) map to crossbars; attention QK^T/AV MatMuls, softmax, RoPE,
+routing and the SSD scan are ALU (DCOM) operators.  Attention is per
+head (grouped-query heads read their K/V head), MLA and routed experts
+are ``workloads.deepseek_v2``'s layers, and a MoE layer holds every
+expert of its router.
 """
 from __future__ import annotations
 
-from typing import List
-
 from ..configs import get_config
-from ..core.graph import Graph, Node
+from ..core.graph import Graph
+from .deepseek_v2 import Builder, Widths, mla_layer, moe_layer
+
+
+def _attention(b: Builder, p: str, h: str, cfg) -> str:
+    d, n_h, n_kv, hd, T = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, \
+        cfg.head_dim, b.tokens
+    x = b.norm(f"{p}norm", h, "attn")
+    qkv = []
+    for name, heads in (("q", n_h), ("k", n_kv), ("v", n_kv)):
+        t = b.gemm(f"{p}{name}", x, d, heads * hd, "attn")
+        t = b.op(f"{p}{name}_heads", "Reshape", [t], "attn",
+                 shape=(T, heads, hd))
+        if name != "v":
+            t = b.rope(f"{p}{name}_rope", t, hd, cfg.rope_theta, "attn")
+        if heads != n_h:
+            t = b.repeat_heads(f"{p}{name}", t, heads, n_h, hd, "attn")
+        qkv.append(b.op(f"{p}{name}_t", "Transpose", [t], "attn",
+                        perm=(1, 0, 2)))
+    o = b.attend(p, *qkv, n_h, hd, hd ** -0.5, "attn")
+    o = b.gemm(f"{p}o", o, n_h * hd, d, "attn")
+    return b.op(f"{p}res", "Add", [h, o], "attn")
+
+
+def _ssm(b: Builder, p: str, h: str, cfg) -> str:
+    x = b.norm(f"{p}norm", h, "ssm")
+    xs = b.gemm(f"{p}w_x", x, cfg.d_model, cfg.d_inner, "ssm")
+    y = b.op(f"{p}ssd", "SSMScan", [xs], "ssm")
+    y = b.gemm(f"{p}out_proj", y, cfg.d_inner, cfg.d_model, "ssm")
+    return b.op(f"{p}res", "Add", [h, y], "ssm")
+
+
+def _mlp(b: Builder, p: str, h: str, cfg, gated: bool) -> str:
+    x = b.norm(f"{p}norm", h, "ffn")
+    if gated:
+        y = b.swiglu(p, x, cfg.d_model, cfg.d_ff, "ffn")
+    else:
+        u = b.gemm(f"{p}up", x, cfg.d_model, cfg.d_ff, "ffn")
+        a = b.op(f"{p}act", "Gelu" if cfg.act == "gelu" else "Silu", [u],
+                 "ffn", frac=4, out_frac=4)
+        y = b.gemm(f"{p}down", a, cfg.d_ff, cfg.d_model, "ffn")
+    return b.op(f"{p}res", "Add", [h, y], "ffn")
 
 
 def lm_block(arch: str, seq: int = 512) -> Graph:
+    """The leading layers and one period of ``arch``'s layer pattern on
+    a (seq, d_model) int8 input named ``input``."""
     cfg = get_config(arch)
-    d, h, k, hd = cfg.d_model, cfg.n_heads, cfg.n_kv_heads, cfg.head_dim
-    nodes: List[Node] = []
-    t = "x"
-
-    def gemm(name, tin, cin, cout):
-        nodes.append(Node(name, "Gemm", [tin], [f"{name}.out"],
-                          {"weight_shape": (cin, cout)}))
-        return f"{name}.out"
-
-    spec = cfg.unit[0]
-    nodes.append(Node("ln1", "RMSNorm", [t], ["ln1.out"]))
-    t_in = "ln1.out"
-
-    if spec.mixer in ("attn", "hybrid", "mla"):
+    w = Widths(hidden=cfg.d_model, heads=cfg.n_heads,
+               qk_nope=cfg.qk_nope_dim, qk_rope=cfg.qk_rope_dim,
+               v_head=cfg.v_head_dim, kv_lora=cfg.kv_lora, d_ff=cfg.d_ff,
+               moe_d_ff=cfg.moe_d_ff, n_experts=cfg.n_experts,
+               top_k=cfg.top_k, n_shared=cfg.n_shared_experts,
+               rope_theta=cfg.rope_theta)
+    b = Builder(seq)
+    h = "input"
+    for i, spec in enumerate(cfg.pre + cfg.unit):
+        p = f"L{i}."
         if spec.mixer == "mla":
-            q = gemm("wq", t_in, d, h * (cfg.qk_nope_dim + cfg.qk_rope_dim))
-            ckv = gemm("w_dkv", t_in, d, cfg.kv_lora + cfg.qk_rope_dim)
-            kk = gemm("w_uk", ckv, cfg.kv_lora + cfg.qk_rope_dim,
-                      h * cfg.qk_nope_dim)
-            v = gemm("w_uv", ckv, cfg.kv_lora + cfg.qk_rope_dim,
-                     h * cfg.v_head_dim)
-            att_dim = h * cfg.v_head_dim
-        else:
-            q = gemm("wq", t_in, d, h * hd)
-            kk = gemm("wk", t_in, d, k * hd)
-            v = gemm("wv", t_in, d, k * hd)
-            att_dim = h * hd
-        nodes.append(Node("qkt", "MatMul", [q, kk], ["qkt.out"],
-                          {"transpose_b": True}))
-        nodes.append(Node("smax", "Softmax", ["qkt.out"], ["smax.out"]))
-        nodes.append(Node("av", "MatMul", ["smax.out", v], ["av.out"]))
-        o = gemm("wo", "av.out", att_dim, d)
-        nodes.append(Node("res1", "Add", [t, o], ["res1.out"]))
-        t = "res1.out"
-
-    if spec.mixer in ("ssm", "hybrid"):
-        di = cfg.d_inner
-        xs = gemm("w_x", t_in, d, di)
-        nodes.append(Node("ssd", "SSMScan", [xs], ["ssd.out"]))
-        op = gemm("out_proj", "ssd.out", di, d)
-        nodes.append(Node("res_s", "Add", [t, op], ["res_s.out"]))
-        t = "res_s.out"
-
-    if spec.mlp != "none":
-        nodes.append(Node("ln2", "RMSNorm", [t], ["ln2.out"]))
+            h = mla_layer(b, f"{p}attn.", h, w)
+        elif spec.mixer in ("attn", "hybrid"):
+            h = _attention(b, f"{p}attn.", h, cfg)
+        if spec.mixer in ("ssm", "hybrid"):
+            h = _ssm(b, f"{p}ssm.", h, cfg)
         if spec.mlp == "moe":
-            nodes.append(Node("router", "TopKRouter", ["ln2.out"],
-                              ["router.out"],
-                              {"n_experts": cfg.n_experts}))
-            outs = []
-            for e in range(cfg.n_experts):
-                hh = gemm(f"e{e}_wi", "ln2.out", d, cfg.moe_d_ff)
-                nodes.append(Node(f"e{e}_act", "Silu", [hh],
-                                  [f"e{e}_act.out"]))
-                outs.append(gemm(f"e{e}_wo", f"e{e}_act.out",
-                                 cfg.moe_d_ff, d))
-            acc = outs[0]
-            for e, o in enumerate(outs[1:], 1):
-                nodes.append(Node(f"moe_add{e}", "Add", [acc, o],
-                                  [f"moe_add{e}.out"]))
-                acc = f"moe_add{e}.out"
-            y = acc
-        else:
-            hh = gemm("wi", "ln2.out", d, cfg.d_ff)
-            nodes.append(Node("act", "Gelu" if cfg.act == "gelu" else "Silu",
-                              [hh], ["act.out"]))
-            y = gemm("wo_mlp", "act.out", cfg.d_ff, d)
-        nodes.append(Node("res2", "Add", [t, y], ["res2.out"]))
-        t = "res2.out"
-
-    return Graph(f"lmblock-{arch}", nodes, {"x": (seq, d)}, [t])
+            h = moe_layer(b, f"{p}moe.", h, w, range(cfg.n_experts))
+        elif spec.mlp != "none":
+            h = _mlp(b, f"{p}mlp.", h, cfg, spec.mlp == "gated")
+    return Graph(f"lmblock-{arch}", b.nodes, {"input": (seq, cfg.d_model)},
+                 [h])

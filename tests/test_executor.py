@@ -173,8 +173,8 @@ def test_executor_split_graph():
                          ids=["exact", "saturating"])
 def test_executor_float_and_matmul_dcom_ops(arch):
     """Attention-style graph: MatMul (transpose_b), Softmax, LayerNorm
-    and Gelu lowerings (incl. the float pure_callback path) stay
-    bit-exact vs the interpreter."""
+    and Gelu lowerings (the integer ALU rules, computed on the device)
+    stay bit-exact vs the interpreter."""
     from repro.core.graph import Graph, Node
     nodes = [
         Node("fc1", "Gemm", ["input"], ["fc1.out"],
